@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common
+from repro.core import analytical
 from repro.core.index import ActiveSegment
 from repro.core.pointers import PoolLayout
 
@@ -63,7 +64,7 @@ def run(fast: bool = True):
     scale = common.FAST if fast else common.FULL
     spec, first, second, f1, f2 = common.corpus(scale)
     layout = PoolLayout(z=common.ZG,
-                        slices_per_pool=common.slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             common.ZG, f2, slack=2.0))
     print("\n== bench_ingest: indexing throughput (paper §3.2) ==")
     rows = []

@@ -12,7 +12,8 @@ drives N rollovers and reports:
     must stay bounded near one segment's demand (asserted), where a
     bump-only allocator would grow linearly with segment count;
   * unified query latency over the active pool + all frozen segments
-    (conjunctions through the fused gap-decode+intersect Pallas kernel);
+    (conjunctions decode the gap blocks, then run the blocked intersect
+    Pallas kernel);
   * the frozen-segment count G under geometric compaction
     (``CompactionPolicy(fanout=2)``) through >= 8 rollovers — asserted
     equal to popcount(#rollovers), i.e. G = O(log N), where the
@@ -50,7 +51,7 @@ def run(fast: bool = True, validate: bool = False):
     ]
     seg_freqs = synth.term_freqs(streams[0], vocab)
     layout = PoolLayout(z=common.ZG,
-                        slices_per_pool=common.slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             common.ZG, seg_freqs, slack=2.5))
     fmax = int(seg_freqs.max())
     max_slices = int(analytical.slices_needed(common.ZG, fmax)) + 2

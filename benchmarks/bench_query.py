@@ -46,7 +46,7 @@ def _build_engine(fast: bool, validate: bool = False):
     ]
     seg_freqs = synth.term_freqs(streams[0], vocab)
     layout = PoolLayout(z=common.ZG,
-                        slices_per_pool=common.slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             common.ZG, seg_freqs, slack=2.5))
     fmax = int(seg_freqs.max())
     max_slices = int(analytical.slices_needed(common.ZG, fmax)) + 2

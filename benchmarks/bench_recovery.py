@@ -58,7 +58,7 @@ def run(fast: bool = True, validate: bool = False):
         vocab=vocab, n_docs=n_docs, max_len=14, seed=23))
     freqs = synth.term_freqs(docs[:docs_per_segment], vocab)
     layout = PoolLayout(z=common.ZG,
-                        slices_per_pool=common.slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             common.ZG, freqs, slack=2.5))
     fmax = int(freqs.max())
     max_slices = int(analytical.slices_needed(common.ZG, fmax)) + 2

@@ -58,7 +58,7 @@ _KIND_CYCLE = ("conjunctive", "topk", "scored", "disjunctive",
 def _engine(vocab, docs_per_segment, docs, validate):
     freqs = synth.term_freqs(docs[:docs_per_segment], vocab)
     layout = PoolLayout(z=common.ZG,
-                        slices_per_pool=common.slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             common.ZG, freqs, slack=2.5))
     fmax = int(freqs.max())
     max_slices = int(analytical.slices_needed(common.ZG, fmax)) + 2

@@ -28,7 +28,7 @@ from repro.core.sharded_index import (ShardedActiveSegment, engine_max_len,
 def _bench_one(n_shards: int, scale, docs: np.ndarray, batch: int):
     _, _, _, _, f2 = common.corpus(scale)
     layout = PoolLayout(z=common.ZG,
-                        slices_per_pool=common.slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             common.ZG, f2, slack=2.0))
     mesh, rules = make_doc_mesh(n_shards)
     seg = ShardedActiveSegment(layout, scale.vocab, mesh, rules=rules)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import analytical
 from repro.core.index import ActiveSegment
 from repro.core.pointers import PoolLayout
 from repro.data import synth
@@ -76,38 +77,6 @@ def queries(scale: BenchScale, kind: str):
                            seed=hash(kind) % 2**31)
 
 
-def slices_per_pool_for(z: Sequence[int], freqs: np.ndarray,
-                        slack: float = 1.3,
-                        start_pools=None) -> Tuple[int, ...]:
-    """Exact per-pool slice demand for a term-frequency vector (+slack).
-
-    start_pools: optional per-term starting pool (SP policies start some
-    terms in later pools, shifting demand toward them)."""
-    P = len(z)
-    mask = freqs > 0
-    sp = (np.zeros(mask.sum(), np.int64) if start_pools is None
-          else np.asarray(start_pools)[mask].astype(np.int64))
-    freqs = freqs[mask]
-    need = np.zeros(P, np.int64)
-    sizes = [2 ** int(s) for s in z]
-    for f, p0 in zip(freqs, sp):
-        remaining = int(f)
-        for p in range(int(p0), P):
-            # pools > 0 always burn slot 0 on the previous-pointer (even
-            # for an SP-started chain's first slice, which stores NULL)
-            cap = sizes[p] - (1 if p > 0 else 0)
-            if p < P - 1:
-                need[p] += 1
-                remaining -= cap
-                if remaining <= 0:
-                    break
-            else:
-                need[p] += max(-(-remaining // max(cap, 1)), 1)
-                break
-    need = np.maximum((need * slack).astype(np.int64), 8)
-    return tuple(int(x) for x in need)
-
-
 def build_segment(z: Sequence[int], scale: BenchScale,
                   term_start_pools=None) -> Tuple[ActiveSegment, dict]:
     """Index the SECOND corpus half under config z (paper §8 protocol)."""
@@ -115,7 +84,7 @@ def build_segment(z: Sequence[int], scale: BenchScale,
     sp = (None if term_start_pools is None
           else np.asarray(term_start_pools))
     layout = PoolLayout(z=tuple(z),
-                        slices_per_pool=slices_per_pool_for(
+                        slices_per_pool=analytical.slices_per_pool(
                             z, f2, start_pools=sp))
     seg = ActiveSegment(layout, scale.vocab)
     t0 = time.perf_counter()

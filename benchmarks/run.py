@@ -14,6 +14,10 @@ metrics to a machine-readable file (CI uploads ``BENCH_ci.json`` as a
 build artifact so the perf trajectory accumulates across commits).  Any
 suite failure exits 1 so CI can gate on benchmarks.
 
+The report names the device it ran on (``device``: platform, kind,
+count).  JAX's persistent compile cache is placed by
+:func:`repro.compile_cache.enable`.
+
 ``--warmup K`` runs each suite K extra times first (untimed, metrics
 discarded) and ``--repeat N`` then times N runs, reporting the MINIMUM
 as ``wall_s`` (all runs under ``wall_s_runs``) — so the docs/s and
@@ -79,8 +83,15 @@ def main(argv=None) -> None:
         sys.exit(2)
     fast = not args.full
 
+    import jax
+    from repro import compile_cache
+    print(f"compile cache: {compile_cache.enable()}")
+    devices = jax.devices()
     t_all = time.perf_counter()
     report = {"fast": fast, "repeat": args.repeat, "warmup": args.warmup,
+              "device": {"platform": devices[0].platform,
+                         "kind": devices[0].device_kind,
+                         "count": len(devices)},
               "suites": {}, "failures": []}
     for name in picked:
         t_run = time.perf_counter()   # restarted before every run so a

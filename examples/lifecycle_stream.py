@@ -6,7 +6,7 @@ compressed read-only CSR, their slices return to the pool free lists and
 the next segment recycles them — so the heap high-water mark plateaus at
 roughly one segment's demand while queries keep seeing the entire
 history, newest tweets first, through one unified path (active slice
-pools + fused decode+intersect kernel over the frozen blocks).
+pools + decoded frozen blocks through the blocked intersect kernel).
 
 Ingest runs the PR-4 batch-parallel BULK allocator (sort occurrences by
 term, walk the slice-size progression analytically, allocate batch-wide,

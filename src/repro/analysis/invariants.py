@@ -82,6 +82,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.core.pointers import NULL, PoolLayout, decode_host
+from repro.core.slicepool import row_words
 
 INVALID = 0xFFFFFFFF
 
@@ -153,8 +154,9 @@ def _check_pool_state_one(layout: PoolLayout, heap, watermark, tail, freq,
     wm = watermark.astype(np.int64)
     fc = free_count.astype(np.int64)
 
-    if heap.shape != (layout.total_slots,):
-        rep.add("heap", f"shape {heap.shape} != ({layout.total_slots},)")
+    if heap.shape != (row_words(layout.total_slots),):
+        rep.add("heap", f"shape {heap.shape} != "
+                f"({row_words(layout.total_slots)},)")
         return
     if np.any(wm < 0) or np.any(wm > caps):
         rep.add("watermark", f"outside [0, capacity]: {wm} vs {caps}")

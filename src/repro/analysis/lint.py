@@ -68,10 +68,8 @@ DONATING_FACTORIES = ("make_bulk_ingest_fn", "make_scan_ingest_fn")
 # Fallback pin list if no sibling compat.py can be parsed (kept in sync
 # with kernels/compat.py::_PltpuCompat by test_analysis.py).
 FALLBACK_PINNED = frozenset({
-    "MemorySpace", "TPUMemorySpace", "ANY", "VMEM", "SMEM", "CMEM",
-    "SEMAPHORE", "PrefetchScalarGridSpec", "SemaphoreType",
-    "dma_semaphore", "semaphore", "make_async_copy",
-    "make_async_remote_copy",
+    "MemorySpace", "ANY", "VMEM", "SMEM", "PrefetchScalarGridSpec",
+    "SemaphoreType", "make_async_copy",
 })
 
 _STATIC_ATTRS = {"shape", "ndim", "size", "itemsize", "dtype"}

@@ -17,12 +17,11 @@ routes through here, so tests and benchmarks flip one flag to run the
 whole oracle surface under the sanitizer (CI runs the kernel-equivalence
 suite that way; see .github/workflows/ci.yml).
 
-Known limitation (jax 0.4.37): checkify cannot functionalize an
-interpret-mode ``pallas_call`` (its jaxpr carries input effects checkify
-refuses to discharge — ``JaxprInputEffect ... is invalid``).  ``checked``
-therefore always sanitizes the **jnp oracle**, which repo policy already
-declares to be the semantics (DESIGN.md / ops.py docstrings); the Pallas
-body itself is covered by the oracle-equivalence tests.
+``checked`` always sanitizes the **jnp oracle**, which repo policy
+declares to be the semantics (ops.py docstring): checkify instruments
+XLA operations, and a kernel compiled by Mosaic on a TPU is opaque to
+it.  The Pallas bodies are covered by the oracle-equivalence tests in
+interpret mode and by the TPU compile tests (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 

@@ -179,6 +179,40 @@ def memory_slots_sp(z: Config, f, start_pool) -> np.ndarray:
     return out
 
 
+def slices_per_pool(z: Config, freqs, slack: float = 1.3,
+                    start_pools=None) -> Tuple[int, ...]:
+    """Per-pool slice demand of a term-frequency vector, times ``slack``
+    (floor 8 per pool) — the ``slices_per_pool`` of a
+    :class:`~repro.core.pointers.PoolLayout` sized for one segment.
+
+    A term starting in pool ``p0`` takes one slice from every pool its
+    postings reach below the last, then as many last-pool slices as its
+    remainder needs; pools > 0 burn slot 0 on the previous-pointer (even
+    for an SP-started chain's first slice, which stores NULL).
+    ``start_pools``: optional per-term starting pool (SP policies start
+    some terms in later pools, shifting demand toward them)."""
+    f = np.asarray(freqs, dtype=np.int64)
+    live = f > 0
+    f = f[live]
+    p0 = (np.zeros(f.size, np.int64) if start_pools is None
+          else np.asarray(start_pools, np.int64)[live])
+    P = len(z)
+    caps = np.asarray([(1 << zp) - (1 if p > 0 else 0)
+                       for p, zp in enumerate(z)], np.int64)
+    cum = np.concatenate([[0], np.cumsum(caps)])    # cum[k] = caps[:k]
+    need = np.zeros(P, np.int64)
+    for p in range(P):
+        before = cum[p] - cum[np.minimum(p0, p)]    # absorbed by [p0, p)
+        reached = (p >= p0) & (f > before)
+        if p < P - 1:
+            need[p] = int(np.sum(reached))
+        else:
+            rest = f[reached] - before[reached]
+            need[p] = int(np.sum(np.maximum(-(-rest // caps[p]), 1)))
+    need = np.maximum((need * slack).astype(np.int64), 8)
+    return tuple(int(x) for x in need)
+
+
 def config_space(slice_range=(0, 12), pools_range=(4, 8),
                  max_configs: int | None = None):
     """Yield strictly-increasing Z configs (paper §6 search space)."""

@@ -13,9 +13,9 @@ module drives that loop continuously and gives it a UNIFIED query path:
   * **Frozen segments** — each frozen segment is wrapped in a
     :class:`PackedSegment`: per-term GLOBAL docid lists gap-compressed
     into 128-docid byte-width blocks
-    (:mod:`repro.kernels.segment_intersect`).  Conjunctions run the
-    fused decode+intersect Pallas kernel per segment — the compressed
-    blocks are decoded on the VPU, never walked host-side.
+    (:mod:`repro.kernels.segment_intersect`).  Conjunctions decode the
+    compressed blocks on the device and intersect them with the blocked
+    two-pointer Pallas kernel — never walked host-side.
   * **Merge** — every segment owns a disjoint ascending docid range, so
     per-segment descending lists concatenated newest-segment-first ARE
     the global reverse-chronological result: bit-identical to a
@@ -168,9 +168,10 @@ def conjunctive_packed(pseg: PackedSegment, terms: Sequence[int], *,
                        use_kernel: bool = True,
                        interpret: Optional[bool] = None) -> np.ndarray:
     """Descending GLOBAL docids holding every term, within one frozen
-    segment.  The driving intersection runs the fused decode+intersect
-    kernel on the two smallest compressed lists; further terms fold in
-    with the vectorised membership test on the already-compacted list."""
+    segment.  The driving intersection decodes the two smallest
+    compressed lists and runs the blocked intersect kernel on them;
+    further terms fold in with the vectorised membership test on the
+    already-compacted list."""
     packs = sorted((pseg.packed(t) for t in terms), key=lambda p: p.n)
     if not packs or packs[0].n == 0:
         return np.zeros(0, np.int64)
@@ -1025,6 +1026,11 @@ class ShardedLifecycleEngine(_LifecycleBase):
             rules=self.segments.rules, use_kernel=use_kernel,
             interpret=interpret)
         self._init_shell(batched_kernel, admission)
+        if batched_kernel is None and mesh.size > 1:
+            # the frozen merge is one program replicated over the mesh,
+            # and the TPU compiler cannot partition a Mosaic kernel: the
+            # frozen intersections take the jnp path (masks identical).
+            self._batched_kernel = False
 
     def _active_batch(self, kind: str, *args):
         """The sharded engine is ALREADY batched: one shard_map with one

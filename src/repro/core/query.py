@@ -122,9 +122,9 @@ def make_engine(layout: PoolLayout, max_slices: int, max_len: int,
     """Build a query engine.
 
     ``use_kernel=True`` routes conjunctive intersections through the
-    Pallas ``postings_intersect`` kernel (tiled two-pointer merge on the
-    VPU) instead of the jnp ``searchsorted`` membership test; both yield
-    bit-identical masks, so results do not depend on the flag.
+    Pallas ``postings_intersect`` kernel (blocked two-pointer merge on
+    the VPU) instead of the jnp ``searchsorted`` membership test; both
+    yield bit-identical masks, so results do not depend on the flag.
     ``interpret`` is forwarded to the kernel (None = auto: interpret
     everywhere but real TPU backends).
 
@@ -137,12 +137,9 @@ def make_engine(layout: PoolLayout, max_slices: int, max_len: int,
 
     if use_kernel:
         from repro.kernels import ops
-        from repro.kernels.postings_intersect import pick_tile
-        tile = pick_tile(max_len)
 
         def _intersect(a, na, b, nb):
-            mask = ops.intersect_mask(a, b, ta=tile, tb=tile,
-                                      interpret=interpret)
+            mask = ops.intersect_mask(a, b, interpret=interpret)
             return _compact(a, mask.astype(bool))
     else:
         _intersect = intersect_asc

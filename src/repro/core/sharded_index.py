@@ -46,7 +46,7 @@ from typing import Callable, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import postings as post
@@ -303,7 +303,7 @@ def _make_sharded_ingest(layout: PoolLayout, vocab_size: int,
     fn = shard_map(
         body, mesh=mesh,
         in_specs=(sspec, P(d, None, None), P(), P(None)),
-        out_specs=sspec, check_rep=False)
+        out_specs=sspec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -374,7 +374,7 @@ def make_sharded_engine(layout: PoolLayout, mesh: Mesh,
         return jax.jit(shard_map(
             body, mesh=mesh,
             in_specs=(sspec,) + (P(),) * n_qargs,
-            out_specs=(P(), P()), check_rep=False))
+            out_specs=(P(), P()), check_vma=False))
 
     conjunctive = _sharded(local.conjunctive_asc, 2)
     disjunctive = _sharded(local.disjunctive_asc, 2)
@@ -407,7 +407,7 @@ def make_sharded_engine(layout: PoolLayout, mesh: Mesh,
     conjunctive_scored = jax.jit(shard_map(
         scored_body, mesh=mesh,
         in_specs=(sspec, P(), P()),
-        out_specs=(P(), P(), P()), check_rep=False))
+        out_specs=(P(), P(), P()), check_vma=False))
 
     return ShardedQueryEngine(conjunctive, disjunctive, phrase,
                               topk_conjunctive, conjunctive_scored, S,

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core import pointers as ptr_mod
 from repro.core.pointers import NULL, PoolLayout
+from repro.kernels.bulk_append import ROW
 
 # Shared lru_cache bound for the jitted-function factories (ingest fns,
 # query engines, qexec active-path fns).  A long-lived process cycling
@@ -56,7 +57,7 @@ FACTORY_CACHE_SIZE = 64
 
 
 class PoolState(NamedTuple):
-    heap: jax.Array        # uint32[total_slots]
+    heap: jax.Array        # uint32[row_words(total_slots)]
     watermark: jax.Array   # int32[P] next never-used slice per pool
     tail: jax.Array        # uint32[V]
     freq: jax.Array        # int32[V]
@@ -65,9 +66,17 @@ class PoolState(NamedTuple):
     free_count: jax.Array  # int32[P] live entries in each pool's region
 
 
+def row_words(n: int) -> int:
+    """``n`` rounded up to whole rows of the ingest kernel (128 words, the
+    unit its DMAs move).  The heap is allocated at this length so the
+    kernel updates it in place; the words past ``total_slots`` are never
+    addressed."""
+    return -(-n // ROW) * ROW
+
+
 def init_state(layout: PoolLayout, vocab_size: int) -> PoolState:
     return PoolState(
-        heap=jnp.zeros((layout.total_slots,), jnp.uint32),
+        heap=jnp.zeros((row_words(layout.total_slots),), jnp.uint32),
         watermark=jnp.zeros((layout.num_pools,), jnp.int32),
         tail=jnp.full((vocab_size,), NULL, jnp.uint32),
         freq=jnp.zeros((vocab_size,), jnp.int32),
@@ -149,8 +158,8 @@ def _insert_one(layout: PoolLayout, tbl, caps, state: PoolState,
     """Branchless single-posting insert (one scan step)."""
     pb = layout.pool_bits
     P = layout.num_pools
-    total = layout.total_slots
-    oob = jnp.uint32(total)  # writes with mode="drop" go here when disabled
+    # writes with mode="drop" go here when disabled (past the row padding)
+    oob = jnp.uint32(state.heap.shape[0])
 
     t = state.tail[term]
     new = ptr_mod.is_null(t)
@@ -309,7 +318,7 @@ def make_bulk_ingest_fn(layout: PoolLayout, vocab_size: int, *,
     pb = layout.pool_bits
     P = layout.num_pools
     V = vocab_size
-    H = layout.total_slots
+    H = row_words(layout.total_slots)   # skips land past the heap's rows
     caps = jnp.asarray(layout.slices_per_pool, jnp.int32)
     h_np, excl_np = _progression_tables(layout)
     h_tbl = jnp.asarray(h_np, jnp.int32)

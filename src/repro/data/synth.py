@@ -59,6 +59,44 @@ def zipf_corpus(spec: CorpusSpec) -> np.ndarray:
     return docs
 
 
+class TweetStream:
+    """The :func:`zipf_corpus` distribution as an endless stream, made
+    batch by batch: batch ``i`` depends only on ``(spec.seed, i)``, so a
+    deployment-size stream never exists as one host matrix and any batch
+    can be regenerated on its own.  Term ids are Zipf ranks shuffled by
+    :attr:`rank_to_term`; rows are ``width`` wide, -1-padded, with
+    lengths ``clip(Poisson(mean_len), 1, min(max_len, width))``."""
+
+    def __init__(self, spec: CorpusSpec, width: int = 32):
+        self.spec = spec
+        self.width = min(width, spec.max_len)
+        rng = np.random.default_rng(spec.seed)
+        self.probs = _zipf_probs(spec.vocab, spec.alpha)
+        self.rank_to_term = rng.permutation(spec.vocab)
+        self._cdf = np.cumsum(self.probs)
+
+    def batch(self, index: int, n_docs: int) -> np.ndarray:
+        """int32[n_docs, width] term ids of batch ``index``, -1-padded."""
+        rng = np.random.default_rng([self.spec.seed, int(index)])
+        lens = np.clip(rng.poisson(self.spec.mean_len, n_docs), 1,
+                       self.width)
+        ranks = np.searchsorted(self._cdf, rng.random(int(lens.sum())),
+                                side="right")
+        ranks = np.minimum(ranks, self.spec.vocab - 1)
+        docs = np.full((n_docs, self.width), -1, np.int32)
+        docs[np.arange(self.width)[None, :] < lens[:, None]] = \
+            self.rank_to_term[ranks]
+        return docs
+
+    def expected_freqs(self, n_docs: int) -> np.ndarray:
+        """float64[vocab]: expected postings per term id in ``n_docs``
+        documents (mean length ~ ``mean_len``; the clip moves it by less
+        than 1e-5 at the default 11)."""
+        out = np.empty(self.spec.vocab)
+        out[self.rank_to_term] = self.probs * n_docs * self.spec.mean_len
+        return out
+
+
 def corpus_halves(spec: CorpusSpec) -> Tuple[np.ndarray, np.ndarray]:
     """Chronological split: first half for history, second for experiments
     (paper §8)."""

@@ -19,7 +19,7 @@ import re
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.dist import sharding as _sh
 
@@ -54,13 +54,21 @@ def require_devices(n: int) -> None:
             f"collectives.force_host_device_count)")
 
 
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axis types.  The rules layer places
+    arrays with ``with_sharding_constraint`` (:func:`sharding.constrain`),
+    which the default ``Explicit`` axes of ``jax.make_mesh`` refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def host_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     """Mesh over the (possibly emulated) host devices."""
     n = 1
     for s in shape:
         n *= s
     require_devices(n)
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return auto_mesh(shape, axes)
 
 
 # ---------------------------------------------------------------------------

@@ -1,19 +1,18 @@
-"""Pallas TPU API compatibility layer.
+"""Pallas TPU API surface, pinned in one place.
 
-The kernels in this package target the current ``jax.experimental.pallas.
-tpu`` surface (``pltpu.MemorySpace``, callable scratch constructors,
-``PrefetchScalarGridSpec``).  Pinned/older JAX releases expose the same
-functionality under earlier names (``TPUMemorySpace``) or not at all, and
-future ones rename again.  Policy (see ROADMAP.md): kernels NEVER import
-``jax.experimental.pallas.tpu`` directly — they import the ``pltpu``
-proxy below, which pins the spelling here, in exactly one place.
+Policy (see ROADMAP.md): kernels NEVER import ``jax.experimental.pallas.
+tpu`` directly — they import the ``pltpu`` proxy below, which pins the
+spelling of every name they use to the installed JAX (0.9).  When a JAX
+upgrade renames Pallas API, fix this file only.
 
-Aliased symbols:
-  MemorySpace / TPUMemorySpace   whichever the installed JAX has backs both
-  ANY / VMEM / SMEM / CMEM / SEMAPHORE   memory-space members, module-level
+Pinned symbols:
+  MemorySpace                    the TPU memory-space enum
+  ANY                            ``pl.ANY``: the array stays where XLA put
+                                 it (HBM); kernels DMA from it by hand
+  VMEM / SMEM                    scratch-shape constructors
   PrefetchScalarGridSpec         scalar-prefetch grid spec
-  SemaphoreType / dma_semaphore / semaphore   DMA + regular semaphores
-Everything else falls through to the real module via ``__getattr__``.
+  SemaphoreType                  DMA semaphores
+  make_async_copy                local DMA
 """
 from __future__ import annotations
 
@@ -21,85 +20,16 @@ from jax.experimental import pallas as pl  # noqa: F401  (re-export)
 from jax.experimental.pallas import tpu as _tpu
 
 
-def _first(*names):
-    for name in names:
-        obj = getattr(_tpu, name, None)
-        if obj is not None:
-            return obj
-    return None
-
-
-class _MissingSymbol:
-    """Stand-in whose every use fails loudly, pointing here."""
-
-    def __init__(self, name):
-        self._name = name
-
-    def _raise(self):
-        raise ImportError(
-            f"jax.experimental.pallas.tpu has no {self._name!r} under this "
-            f"JAX version; update repro.kernels.compat")
-
-    def __call__(self, *a, **k):
-        self._raise()
-
-    def __getattr__(self, attr):
-        self._raise()
-
-
-def _required(*names):
-    obj = _first(*names)
-    return obj if obj is not None else _MissingSymbol(names[0])
-
-
-# Memory spaces: new JAX spells it MemorySpace, old ones TPUMemorySpace.
-# Both carry ANY/VMEM/SMEM members, so one enum can back both names.
-MemorySpace = _first("MemorySpace", "TPUMemorySpace")
-TPUMemorySpace = _first("TPUMemorySpace", "MemorySpace")
-if MemorySpace is None:  # pragma: no cover - no known JAX hits this
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither MemorySpace nor "
-        "TPUMemorySpace; this JAX version is unsupported by repro.kernels")
-
-# Module-level space members (scratch-shape constructors on TPU pallas).
-ANY = getattr(_tpu, "ANY", MemorySpace.ANY)
-VMEM = getattr(_tpu, "VMEM", MemorySpace.VMEM)
-SMEM = getattr(_tpu, "SMEM", MemorySpace.SMEM)
-CMEM = _required("CMEM")
-SEMAPHORE = _required("SEMAPHORE")
-
-PrefetchScalarGridSpec = _first("PrefetchScalarGridSpec")
-if PrefetchScalarGridSpec is None:  # pragma: no cover
-    raise ImportError(
-        "jax.experimental.pallas.tpu has no PrefetchScalarGridSpec; "
-        "update repro.kernels.compat for this JAX version")
-
-SemaphoreType = _required("SemaphoreType")
-dma_semaphore = _required("dma_semaphore")
-semaphore = _required("semaphore")
-make_async_copy = _required("make_async_copy")
-make_async_remote_copy = _required("make_async_remote_copy")
-
-
 class _PltpuCompat:
-    """``pltpu`` stand-in: compat aliases first, real module second."""
+    """``pltpu`` stand-in: exactly the names the kernels use."""
 
-    MemorySpace = MemorySpace
-    TPUMemorySpace = TPUMemorySpace
-    ANY = ANY
-    VMEM = VMEM
-    SMEM = SMEM
-    CMEM = CMEM
-    SEMAPHORE = SEMAPHORE
-    PrefetchScalarGridSpec = PrefetchScalarGridSpec
-    SemaphoreType = SemaphoreType
-    dma_semaphore = dma_semaphore
-    semaphore = semaphore
-    make_async_copy = staticmethod(make_async_copy)
-    make_async_remote_copy = staticmethod(make_async_remote_copy)
-
-    def __getattr__(self, name):
-        return getattr(_tpu, name)
+    MemorySpace = _tpu.MemorySpace
+    ANY = pl.ANY
+    VMEM = _tpu.VMEM
+    SMEM = _tpu.SMEM
+    PrefetchScalarGridSpec = _tpu.PrefetchScalarGridSpec
+    SemaphoreType = _tpu.SemaphoreType
+    make_async_copy = staticmethod(_tpu.make_async_copy)
 
 
 pltpu = _PltpuCompat()

@@ -67,7 +67,7 @@ def embedding_bag(table, indices, offsets, *, mode: str = "sum",
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
         out_specs=pl.BlockSpec((1, D), lambda b, *_: (b, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, 1, D), table.dtype),   # double-buffered rows

@@ -1,18 +1,18 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (CPU CI / this container executes
-the kernel bodies in Python for correctness); on a TPU backend the same
-calls compile to Mosaic.  The jnp oracles live in ref.py and back both the
-allclose tests and the dry-run lowering path (DESIGN.md: kernels are the
-TPU target, the jnp path is the semantics).
+``interpret`` defaults to True off-TPU (tests run on the CPU backend and
+execute the kernel bodies in Python for correctness); on a TPU backend
+the same calls compile to Mosaic.  The jnp oracles live in ref.py and
+back both the equivalence tests and the CPU execution path (the kernels
+are the TPU target, the jnp path is the semantics).
 
 ``checked=True`` on the postings/segment/bulk wrappers runs the call
 under ``jax.experimental.checkify`` (index OOB + NaN + div, via
 repro.analysis.sanitize) and raises ``sanitize.SanitizerError`` on the
-first violation.  checkify cannot functionalize an interpret-mode
-``pallas_call`` on this JAX, so the checked route always sanitizes the
-jnp oracle — which IS the semantics — regardless of ``use_kernel``/
-``interpret``.  CI's checked leg runs the kernel-equivalence suite this
+first violation.  The checked route always sanitizes the jnp oracle —
+which IS the semantics — regardless of ``use_kernel``/``interpret``:
+checkify instruments XLA operations, and a compiled Mosaic kernel is
+opaque to it.  CI's checked leg runs the kernel-equivalence suite this
 way (REPRO_CHECKED=1).
 """
 from __future__ import annotations
@@ -25,6 +25,7 @@ from repro.kernels.bulk_append import bulk_append as _bulk_append
 from repro.kernels.embedding_bag import embedding_bag as _embedding_bag
 from repro.kernels.paged_attention import PAGE
 from repro.kernels.paged_attention import paged_attention as _paged_attention
+from repro.kernels.postings_intersect import CHUNK
 from repro.kernels.postings_intersect import intersect_mask as _intersect_mask
 from repro.kernels.segment_intersect import (
     scored_intersect_batched as _scored_intersect_batched,
@@ -52,8 +53,8 @@ def embedding_bag(table, indices, offsets, *, mode: str = "sum",
                           interpret=interpret)
 
 
-def intersect_mask(a, b, *, ta: int = 256, tb: int = 256, interpret=None,
-                   checked: bool = False):
+def intersect_mask(a, b, *, ta: int = CHUNK, tb: int = CHUNK,
+                   interpret=None, checked: bool = False):
     if checked:
         return sanitize.checked_call(ref.intersect_mask_ref, a, b)
     if interpret is None:
@@ -63,7 +64,8 @@ def intersect_mask(a, b, *, ta: int = 256, tb: int = 256, interpret=None,
 
 def segment_intersect_mask(a, b, *, interpret=None,
                            checked: bool = False):
-    """Fused gap-decode + intersection of two PackedLists (frozen path)."""
+    """Gap-decode + blocked intersection of two PackedLists (frozen
+    path)."""
     if checked:
         return sanitize.checked_call(ref.segment_intersect_mask_ref, a, b)
     if interpret is None:

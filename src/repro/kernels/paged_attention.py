@@ -98,8 +98,8 @@ def paged_attention(q, k_heap, v_heap, page_table, lengths, *,
         grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pltpu.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, *_: (b, h, 0, 0)),
         scratch_shapes=[

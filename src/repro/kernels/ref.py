@@ -76,7 +76,7 @@ def bulk_append_ref(heap, tail, freq, post_addr, post_val, ptr_addr,
 
 
 def segment_intersect_mask_ref(a_packed, b_packed):
-    """Oracle for the fused segment kernel: decode both PackedLists with
+    """Oracle for the segment intersect: decode both PackedLists with
     the all-blocks jnp decoder, then plain membership."""
     from repro.kernels.segment_intersect import decode_packed
     a_ids = decode_packed(a_packed)

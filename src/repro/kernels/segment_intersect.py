@@ -1,16 +1,19 @@
-"""Pallas TPU fused gap-decode + sorted-set intersection over frozen
-CSR segments (the lifecycle engine's frozen-path conjunctive hot loop).
+"""Compressed frozen-segment docid lists and their intersections (the
+lifecycle engine's frozen-path conjunctive hot loop).
 
 Frozen read-only segments store each term's docids gap-compressed in
 128-docid blocks (a byte-aligned PForDelta-lite: per-block byte width
-1/2/4, little-endian gap planes — :func:`pack_docids`).  The paper's
+1/2/4, lane-planar gap words — :func:`pack_docids`).  The paper's
 query path decompresses a block and merges; the host-side numpy walk did
-that one Python int at a time.  Here both lists stream through VMEM one
-COMPRESSED block at a time and every block is decoded on the VPU — a
-static byte-plane unpack (no gathers) followed by a prefix-sum over the
-gap lanes — fused with the same tiled two-pointer intersection rule as
-``postings_intersect``: one 128 x 128 equality matrix per (a_block,
-b_block) pair, advance on block maxima, <= n_a_blocks + n_b_blocks steps.
+that one Python int at a time.  Here a whole ``[N, ...]`` stack decodes
+in XLA (:func:`decode_stacked`: a static byte-plane unpack plus a
+prefix-sum over the gap lanes) and the decoded rows go through the
+blocked two-pointer kernel of :mod:`repro.kernels.postings_intersect`:
+one 128 x 128 equality matrix per (a_block, b_block) pair, advance on
+block maxima, <= n_a_blocks + n_b_blocks steps.  The frozen merge in
+``repro.core.qexec`` decodes the same stacks for its fold anyway, and
+the TPU compiler lowers neither the byte-plane unpack's reshapes nor a
+prefix sum inside a kernel.
 
 Inputs are :class:`PackedList`s (ascending deduped docids).  Output is an
 int32 membership mask over a's decoded docid lanes (1 where lane i < n_a
@@ -18,28 +21,30 @@ and a's docid is present in b); compaction happens in the jnp caller.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.compat import pl, pltpu
+from repro.kernels.postings_intersect import intersect_rows
 
 INVALID = 0xFFFFFFFF
 SEG_BLOCK = 128          # docids per compressed block
-SLAB_WORDS = SEG_BLOCK   # uint32 words DMA'd per block (bw=4 worst case)
+SLAB_WORDS = SEG_BLOCK   # uint32 words read per block (bw=4 worst case)
 SCORE_MAX = 255          # 8-bit quantized impact ceiling (min(tf, 255))
 SCORE_WORDS = SEG_BLOCK // 4   # uint32 words per block's packed score plane
+
 
 class PackedList(NamedTuple):
     """One term's docid list, block-gap-compressed and device-ready.
 
     ``woffs[b]`` is the start word of block b's gap plane inside
-    ``payload``; the plane holds 32 * bw words (bw = bytes per gap), and
+    ``payload``; the plane holds 32 * bw words (bw = bytes per gap),
+    lane-planar (byte or half-word ``k`` of word ``m`` is lane
+    ``k * 32 * bw + m``), and
     ``payload`` carries SLAB_WORDS trailing pad words so a fixed-size
-    block DMA never overruns.  Lane 0's gap is stored as 0, so a block
+    block read never overruns.  Lane 0's gap is stored as 0, so a block
     decodes as ``firsts[b] + cumsum(gaps)``.  The last block is padded by
     repeating the final docid (gap 0) — harmless for membership tests,
     masked out of the output by ``n``.
@@ -96,8 +101,7 @@ def pack_docids(ids: np.ndarray) -> PackedList:
         g_max = int(gaps.max())
         bw = 1 if g_max < (1 << 8) else 2 if g_max < (1 << 16) else 4
         bws[b] = bw
-        dt = {1: "<u1", 2: "<u2", 4: "<u4"}[bw]
-        plane = np.ascontiguousarray(gaps.astype(dt)).view("<u4")
+        plane = _planar_words(gaps.astype({1: "<u1", 2: "<u2", 4: "<u4"}[bw]))
         woffs[b] = words_so_far
         words_so_far += plane.size
         planes.append(plane)
@@ -218,28 +222,39 @@ def decode_stacked(s: StackedLists) -> jax.Array:
                      jnp.uint32(INVALID))
 
 
-def _plane_shifts(shape, bits_each: int):
-    """Per-lane shift amounts as a broadcasted iota over the last axis
-    (Pallas kernels cannot capture constant arrays, and TPU iota must be
-    multi-dimensional anyway)."""
-    sh = jax.lax.broadcasted_iota(jnp.uint32, shape, len(shape) - 1)
-    return sh * jnp.uint32(bits_each)
+def _planar_words(lanes: np.ndarray) -> np.ndarray:
+    """Pack the narrow lanes of 128-lane blocks (``[..., 128]`` uint8 or
+    uint16) into lane-planar uint32 words: sub-word ``k`` of word ``m``
+    holds lane ``k * n_words + m``.  Host-side, at freeze time."""
+    per = 4 // lanes.itemsize
+    lead = lanes.shape[:-1]
+    n_words = SEG_BLOCK // per
+    planes = lanes.reshape(lead + (per, n_words)).swapaxes(-1, -2)
+    return np.ascontiguousarray(planes).view("<u4").reshape(lead + (n_words,))
+
+
+def _unplane(words, bits: int):
+    """Inverse of :func:`_planar_words` on the device: ``[..., n]`` words
+    -> ``[..., n * 32 // bits]`` lanes.  Each sub-word plane is a shift
+    and a mask of the whole word row, and the planes concatenate along
+    the lane axis, so no array ever has a narrow minor dimension (an
+    interleaved layout needs a ``[..., n, 4]`` relayout, which the TPU
+    compiler took minutes over at deployment widths)."""
+    mask = jnp.uint32((1 << bits) - 1)
+    return jnp.concatenate(
+        [(words >> jnp.uint32(bits * k)) & mask for k in range(32 // bits)],
+        axis=-1)
 
 
 def _unpack_gaps(slab, bw):
     """Decode one block's gap lanes from its (up to) 128-word slab.
 
-    Static byte-plane unpack — every width reads a fixed reshape of the
+    Static byte-plane unpack — every width reads fixed planes of the
     slab, selected with ``where`` — so the VPU never gathers.
     ``slab``: uint32[..., SLAB_WORDS]; ``bw``: int32[...] broadcastable.
     """
-    lead = slab.shape[:-1]
-    s8 = _plane_shifts(lead + (SEG_BLOCK // 4, 4), 8)
-    s16 = _plane_shifts(lead + (SEG_BLOCK // 2, 2), 16)
-    b1 = ((slab[..., : SEG_BLOCK // 4, None] >> s8) & jnp.uint32(0xFF))
-    b2 = ((slab[..., : SEG_BLOCK // 2, None] >> s16) & jnp.uint32(0xFFFF))
-    b1 = b1.reshape(lead + (SEG_BLOCK,))
-    b2 = b2.reshape(lead + (SEG_BLOCK,))
+    b1 = _unplane(slab[..., : SEG_BLOCK // 4], 8)
+    b2 = _unplane(slab[..., : SEG_BLOCK // 2], 16)
     bw = jnp.asarray(bw)[..., None]
     return jnp.where(bw == 1, b1, jnp.where(bw == 2, b2, slab))
 
@@ -262,235 +277,21 @@ def decode_packed(packed: PackedList) -> jax.Array:
     return jnp.where(lane < packed.n, flat, jnp.uint32(INVALID))
 
 
-def _kernel(a_firsts, a_bws, a_woffs, b_firsts, b_bws, b_woffs, n_valid,
-            a_hbm, b_hbm, o_hbm, a_slab, b_slab, m_buf,
-            sem_a, sem_b, sem_o, *, na_blocks: int, nb_blocks: int):
-    def copy_a(ia):
-        return pltpu.make_async_copy(
-            a_hbm.at[pl.ds(a_woffs[ia], SLAB_WORDS)], a_slab, sem_a)
-
-    def copy_b(ib):
-        return pltpu.make_async_copy(
-            b_hbm.at[pl.ds(b_woffs[ib], SLAB_WORDS)], b_slab, sem_b)
-
-    def flush(ia):
-        cp = pltpu.make_async_copy(
-            m_buf, o_hbm.at[pl.ds(ia * SEG_BLOCK, SEG_BLOCK)], sem_o)
-        cp.start()
-        cp.wait()
-
-    copy_a(0).start()
-    copy_a(0).wait()
-    copy_b(0).start()
-    copy_b(0).wait()
-    m_buf[...] = jnp.zeros((SEG_BLOCK,), jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (SEG_BLOCK, 1), 0)
-    lane = lane.reshape(SEG_BLOCK)
-
-    def step(_, carry):
-        ia, ib = carry
-        live = ia < na_blocks
-        iam = jnp.minimum(ia, na_blocks - 1)
-        ibm = jnp.minimum(ib, nb_blocks - 1)
-        # fused decode: byte-plane unpack + gap prefix-sum, in VMEM.
-        a_ids = a_firsts[iam] + jnp.cumsum(
-            _unpack_gaps(a_slab[...], a_bws[iam]), dtype=jnp.uint32)
-        b_ids = b_firsts[ibm] + jnp.cumsum(
-            _unpack_gaps(b_slab[...], b_bws[ibm]), dtype=jnp.uint32)
-        valid = (iam * SEG_BLOCK + lane) < n_valid[0]
-        eq = (a_ids[:, None] == b_ids[None, :]) & valid[:, None]
-        hits = jnp.max(eq.astype(jnp.int32), axis=1)
-        m_buf[...] = jnp.where(live, jnp.maximum(m_buf[...], hits),
-                               m_buf[...])
-        a_max = a_ids[SEG_BLOCK - 1]   # pad repeats the last docid
-        b_max = b_ids[SEG_BLOCK - 1]
-        b_done = ib >= nb_blocks - 1
-        adv_a = live & ((a_max <= b_max) | b_done)
-        adv_b = live & ((b_max <= a_max) & ~b_done)
-
-        @pl.when(adv_a)
-        def _():
-            flush(iam)
-            m_buf[...] = jnp.zeros((SEG_BLOCK,), jnp.int32)
-
-        ia2 = ia + adv_a.astype(jnp.int32)
-        ib2 = ib + adv_b.astype(jnp.int32)
-
-        @pl.when(adv_a & (ia2 < na_blocks))
-        def _():
-            cp = copy_a(ia2)
-            cp.start()
-            cp.wait()
-
-        @pl.when(adv_b)
-        def _():
-            cp = copy_b(ib2)
-            cp.start()
-            cp.wait()
-
-        return ia2, ib2
-
-    jax.lax.fori_loop(0, na_blocks + nb_blocks, step, (0, 0))
-
-
-@functools.partial(jax.jit, static_argnames=("na_blocks", "nb_blocks",
-                                             "interpret"))
-def _call(a_firsts, a_bws, a_woffs, a_payload,
-          b_firsts, b_bws, b_woffs, b_payload, n_valid, *,
-          na_blocks: int, nb_blocks: int, interpret: bool = True):
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((SLAB_WORDS,), jnp.uint32),
-            pltpu.VMEM((SLAB_WORDS,), jnp.uint32),
-            pltpu.VMEM((SEG_BLOCK,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, na_blocks=na_blocks,
-                          nb_blocks=nb_blocks),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((na_blocks * SEG_BLOCK,), jnp.int32),
-        interpret=interpret,
-    )(a_firsts, a_bws, a_woffs, b_firsts, b_bws, b_woffs, n_valid,
-      a_payload, b_payload)
-
-
 def segment_intersect_mask(a: PackedList, b: PackedList, *,
                            interpret: bool = True) -> jax.Array:
     """Membership mask of a's docids in b, both block-gap-compressed.
 
     Returns int32[a.n_blocks * SEG_BLOCK] (1 where lane < a.n and a's
-    docid occurs in b).  Decode happens inside the kernel; neither list
-    is materialised uncompressed in HBM.
+    docid occurs in b): both lists are decoded with the jnp decoder and
+    walked by the blocked two-pointer kernel
+    (:func:`repro.kernels.postings_intersect.intersect_rows`).
     """
     if a.n_blocks == 0:
         return jnp.zeros((0,), jnp.int32)
     if b.n_blocks == 0:
         return jnp.zeros((a.n_blocks * SEG_BLOCK,), jnp.int32)
-    n_valid = jnp.asarray([a.n], jnp.int32)
-    return _call(a.firsts, a.bws, a.woffs, a.payload,
-                 b.firsts, b.bws, b.woffs, b.payload, n_valid,
-                 na_blocks=a.n_blocks, nb_blocks=b.n_blocks,
-                 interpret=interpret)
-
-
-# ---------------------------------------------------------------------------
-# Batched kernel: one grid step per (query, segment) pair
-# ---------------------------------------------------------------------------
-def _kernel_batched(a_firsts, a_bws, a_woffs, b_firsts, b_bws, b_woffs,
-                    n_valid, a_hbm, b_hbm, o_hbm, a_slab, b_slab, m_buf,
-                    sem_a, sem_b, sem_o, *, na_blocks: int, nb_blocks: int):
-    """One two-pointer walk per grid step ``r`` — row r of the stacked
-    inputs is one (query, segment) pair, so a whole query batch over a
-    whole frozen stack is a single pallas_call with grid=(Q * G,).  Pad
-    rows/blocks (firsts INVALID, zero gap planes) walk through harmlessly:
-    INVALID never equals a valid docid and sorts above every block max."""
-    r = pl.program_id(0)
-
-    def copy_a(ia):
-        return pltpu.make_async_copy(
-            a_hbm.at[r, pl.ds(a_woffs[r, ia], SLAB_WORDS)], a_slab, sem_a)
-
-    def copy_b(ib):
-        return pltpu.make_async_copy(
-            b_hbm.at[r, pl.ds(b_woffs[r, ib], SLAB_WORDS)], b_slab, sem_b)
-
-    def flush(ia):
-        cp = pltpu.make_async_copy(
-            m_buf, o_hbm.at[r, pl.ds(ia * SEG_BLOCK, SEG_BLOCK)], sem_o)
-        cp.start()
-        cp.wait()
-
-    copy_a(0).start()
-    copy_a(0).wait()
-    copy_b(0).start()
-    copy_b(0).wait()
-    m_buf[...] = jnp.zeros((SEG_BLOCK,), jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (SEG_BLOCK, 1), 0)
-    lane = lane.reshape(SEG_BLOCK)
-
-    def step(_, carry):
-        ia, ib = carry
-        live = ia < na_blocks
-        iam = jnp.minimum(ia, na_blocks - 1)
-        ibm = jnp.minimum(ib, nb_blocks - 1)
-        a_ids = a_firsts[r, iam] + jnp.cumsum(
-            _unpack_gaps(a_slab[...], a_bws[r, iam]), dtype=jnp.uint32)
-        b_ids = b_firsts[r, ibm] + jnp.cumsum(
-            _unpack_gaps(b_slab[...], b_bws[r, ibm]), dtype=jnp.uint32)
-        valid = (iam * SEG_BLOCK + lane) < n_valid[r]
-        eq = (a_ids[:, None] == b_ids[None, :]) & valid[:, None]
-        hits = jnp.max(eq.astype(jnp.int32), axis=1)
-        m_buf[...] = jnp.where(live, jnp.maximum(m_buf[...], hits),
-                               m_buf[...])
-        a_max = a_ids[SEG_BLOCK - 1]
-        b_max = b_ids[SEG_BLOCK - 1]
-        b_done = ib >= nb_blocks - 1
-        adv_a = live & ((a_max <= b_max) | b_done)
-        adv_b = live & ((b_max <= a_max) & ~b_done)
-
-        @pl.when(adv_a)
-        def _():
-            flush(iam)
-            m_buf[...] = jnp.zeros((SEG_BLOCK,), jnp.int32)
-
-        ia2 = ia + adv_a.astype(jnp.int32)
-        ib2 = ib + adv_b.astype(jnp.int32)
-
-        @pl.when(adv_a & (ia2 < na_blocks))
-        def _():
-            cp = copy_a(ia2)
-            cp.start()
-            cp.wait()
-
-        @pl.when(adv_b)
-        def _():
-            cp = copy_b(ib2)
-            cp.start()
-            cp.wait()
-
-        return ia2, ib2
-
-    jax.lax.fori_loop(0, na_blocks + nb_blocks, step, (0, 0))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _call_batched(a: StackedLists, b: StackedLists, *,
-                  interpret: bool = True):
-    N, na_blocks = a.firsts.shape
-    nb_blocks = b.firsts.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(N,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((SLAB_WORDS,), jnp.uint32),
-            pltpu.VMEM((SLAB_WORDS,), jnp.uint32),
-            pltpu.VMEM((SEG_BLOCK,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_batched, na_blocks=na_blocks,
-                          nb_blocks=nb_blocks),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, na_blocks * SEG_BLOCK),
-                                       jnp.int32),
-        interpret=interpret,
-    )(a.firsts, a.bws, a.woffs, b.firsts, b.bws, b.woffs,
-      jnp.asarray(a.ns, jnp.int32), a.payload, b.payload)
+    return intersect_rows(decode_packed(a)[None], decode_packed(b)[None],
+                          interpret=interpret)[0]
 
 
 def segment_intersect_mask_batched(a: StackedLists, b: StackedLists, *,
@@ -499,16 +300,18 @@ def segment_intersect_mask_batched(a: StackedLists, b: StackedLists, *,
 
     ``a``/``b`` leaves must carry ONE leading axis ``[N, ...]`` (flatten a
     ``[Q, G]`` query x segment batch first); returns
-    int32[N, a.n_blocks * SEG_BLOCK].  One pallas_call, grid over the
-    (query, segment) pairs — the frozen-path conjunction of a whole
-    query batch in a single dispatch.
+    int32[N, a.n_blocks * SEG_BLOCK].  The stacks decode in XLA (the same
+    :func:`decode_stacked` the frozen merge already runs) and one
+    pallas_call walks every (query, segment) row — the frozen-path
+    conjunction of a whole query batch in a single kernel dispatch.
     """
     assert a.firsts.ndim == 2 and b.firsts.ndim == 2, \
         "stack leaves must be [N, ...]; reshape the (Q, G) batch first"
     if a.n_blocks == 0 or a.firsts.shape[0] == 0:
         return jnp.zeros((a.firsts.shape[0], a.n_blocks * SEG_BLOCK),
                          jnp.int32)
-    return _call_batched(a, b, interpret=interpret)
+    return intersect_rows(decode_stacked(a), decode_stacked(b),
+                          interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -518,8 +321,9 @@ class ScoredList(NamedTuple):
     """A :class:`PackedList` plus its quantized impact plane.
 
     ``swords`` packs one uint8 impact per docid lane, four lanes per
-    little-endian uint32 word, 32 words per 128-docid block — the same
-    lane order as the decoded docids, so lane i of ``decode_packed(ids)``
+    uint32 word, 32 lane-planar words per 128-docid block (byte ``k`` of
+    the block's word ``m`` is lane ``32 k + m``) — decoding to the same
+    lane order as the docids, so lane i of ``decode_packed(ids)``
     scores ``decode_scores(swords)[i]``.  Valid lanes carry impacts in
     [1, SCORE_MAX]; pad lanes (including the repeated-last-docid tail of
     the final real block) and pad blocks are zero, so 0 doubles as the
@@ -544,10 +348,10 @@ def attach_scores(ids: PackedList, scores: np.ndarray) -> ScoredList:
     if ids.n and (scores.min() < 1 or scores.max() > SCORE_MAX):
         raise ValueError("impact scores must be in [1, SCORE_MAX]")
     nb = ids.n_blocks
-    lanes = np.zeros(nb * SEG_BLOCK, np.uint8)
-    lanes[: ids.n] = scores
-    swords = np.ascontiguousarray(lanes).view("<u4")
-    bmax = (lanes.reshape(nb, SEG_BLOCK).max(axis=1).astype(np.int32)
+    lanes = np.zeros((nb, SEG_BLOCK), np.uint8)
+    lanes.reshape(-1)[: ids.n] = scores
+    swords = _planar_words(lanes).reshape(-1)
+    bmax = (lanes.max(axis=1).astype(np.int32)
             if nb else np.zeros(0, np.int32))
     smax = int(scores.max()) if ids.n else 0
     return ScoredList(ids=ids, swords=jnp.asarray(swords),
@@ -606,148 +410,8 @@ def decode_scores(swords: jax.Array) -> jax.Array:
     gap decoder, fixed at one byte per lane."""
     lead = swords.shape[:-1]
     w = swords.shape[-1]
-    sh = _plane_shifts(lead + (w, 4), 8)
-    vals = (swords[..., None] >> sh) & jnp.uint32(0xFF)
-    return vals.reshape(lead + (w * 4,)).astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# Scored batched kernel: fused decode + intersect + impact sum + block-max
-# skip, one grid step per (query, segment) pair
-# ---------------------------------------------------------------------------
-def _scored_kernel_batched(a_firsts, a_bws, a_woffs, b_firsts, b_bws,
-                           b_woffs, n_valid, a_bmax, rest, th,
-                           a_hbm, b_hbm, as_hbm, bs_hbm, o_hbm,
-                           a_slab, b_slab, as_slab, bs_slab, m_buf,
-                           sem_a, sem_b, sem_as, sem_bs, sem_o, *,
-                           na_blocks: int, nb_blocks: int):
-    """Scored variant of :func:`_kernel_batched`.  Row r walks the same
-    two-pointer block pairing, but each eq-match contributes b's impact
-    (unique docids mean at most one real match per a-lane, and b's pad
-    lanes score 0, so a lane-wise max recovers the matched impact).  At
-    flush time a-blocks whose WAND upper bound ``a_bmax + rest`` cannot
-    beat the heap threshold ``th`` are zeroed whole — the output lane
-    value is ``a_impact + b_impact`` for surviving conjunctive hits and
-    0 otherwise."""
-    r = pl.program_id(0)
-
-    def copy_a(ia):
-        return pltpu.make_async_copy(
-            a_hbm.at[r, pl.ds(a_woffs[r, ia], SLAB_WORDS)], a_slab, sem_a)
-
-    def copy_b(ib):
-        return pltpu.make_async_copy(
-            b_hbm.at[r, pl.ds(b_woffs[r, ib], SLAB_WORDS)], b_slab, sem_b)
-
-    def copy_as(ia):
-        return pltpu.make_async_copy(
-            as_hbm.at[r, pl.ds(ia * SCORE_WORDS, SCORE_WORDS)], as_slab,
-            sem_as)
-
-    def copy_bs(ib):
-        return pltpu.make_async_copy(
-            bs_hbm.at[r, pl.ds(ib * SCORE_WORDS, SCORE_WORDS)], bs_slab,
-            sem_bs)
-
-    def flush(ia):
-        cp = pltpu.make_async_copy(
-            m_buf, o_hbm.at[r, pl.ds(ia * SEG_BLOCK, SEG_BLOCK)], sem_o)
-        cp.start()
-        cp.wait()
-
-    for cp in (copy_a(0), copy_b(0), copy_as(0), copy_bs(0)):
-        cp.start()
-        cp.wait()
-    m_buf[...] = jnp.zeros((SEG_BLOCK,), jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (SEG_BLOCK, 1), 0)
-    lane = lane.reshape(SEG_BLOCK)
-
-    def step(_, carry):
-        ia, ib = carry
-        live = ia < na_blocks
-        iam = jnp.minimum(ia, na_blocks - 1)
-        ibm = jnp.minimum(ib, nb_blocks - 1)
-        a_ids = a_firsts[r, iam] + jnp.cumsum(
-            _unpack_gaps(a_slab[...], a_bws[r, iam]), dtype=jnp.uint32)
-        b_ids = b_firsts[r, ibm] + jnp.cumsum(
-            _unpack_gaps(b_slab[...], b_bws[r, ibm]), dtype=jnp.uint32)
-        b_sc = decode_scores(bs_slab[...])
-        valid = (iam * SEG_BLOCK + lane) < n_valid[r]
-        eq = (a_ids[:, None] == b_ids[None, :]) & valid[:, None]
-        matched = jnp.max(eq.astype(jnp.int32) * b_sc[None, :], axis=1)
-        m_buf[...] = jnp.where(live, jnp.maximum(m_buf[...], matched),
-                               m_buf[...])
-        a_max = a_ids[SEG_BLOCK - 1]
-        b_max = b_ids[SEG_BLOCK - 1]
-        b_done = ib >= nb_blocks - 1
-        adv_a = live & ((a_max <= b_max) | b_done)
-        adv_b = live & ((b_max <= a_max) & ~b_done)
-
-        @pl.when(adv_a)
-        def _():
-            a_sc = decode_scores(as_slab[...])
-            keep = (a_bmax[r, iam] + rest[r]) > th[r]
-            hit = m_buf[...] > 0
-            m_buf[...] = jnp.where(keep & hit & valid,
-                                   a_sc + m_buf[...], 0)
-            flush(iam)
-            m_buf[...] = jnp.zeros((SEG_BLOCK,), jnp.int32)
-
-        ia2 = ia + adv_a.astype(jnp.int32)
-        ib2 = ib + adv_b.astype(jnp.int32)
-
-        @pl.when(adv_a & (ia2 < na_blocks))
-        def _():
-            for cp in (copy_a(ia2), copy_as(ia2)):
-                cp.start()
-                cp.wait()
-
-        @pl.when(adv_b)
-        def _():
-            for cp in (copy_b(ib2), copy_bs(ib2)):
-                cp.start()
-                cp.wait()
-
-        return ia2, ib2
-
-    jax.lax.fori_loop(0, na_blocks + nb_blocks, step, (0, 0))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _scored_call_batched(a: ScoredStack, b: ScoredStack, rest, th, *,
-                         interpret: bool = True):
-    N, na_blocks = a.ids.firsts.shape
-    nb_blocks = b.ids.firsts.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=10,
-        grid=(N,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)] * 4,
-        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((SLAB_WORDS,), jnp.uint32),
-            pltpu.VMEM((SLAB_WORDS,), jnp.uint32),
-            pltpu.VMEM((SCORE_WORDS,), jnp.uint32),
-            pltpu.VMEM((SCORE_WORDS,), jnp.uint32),
-            pltpu.VMEM((SEG_BLOCK,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_scored_kernel_batched, na_blocks=na_blocks,
-                          nb_blocks=nb_blocks),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, na_blocks * SEG_BLOCK),
-                                       jnp.int32),
-        interpret=interpret,
-    )(a.ids.firsts, a.ids.bws, a.ids.woffs,
-      b.ids.firsts, b.ids.bws, b.ids.woffs,
-      jnp.asarray(a.ids.ns, jnp.int32), jnp.asarray(a.bmax, jnp.int32),
-      jnp.asarray(rest, jnp.int32), jnp.asarray(th, jnp.int32),
-      a.ids.payload, b.ids.payload, a.swords, b.swords)
+    blocks = swords.reshape(lead + (w // SCORE_WORDS, SCORE_WORDS))
+    return _unplane(blocks, 8).reshape(lead + (w * 4,)).astype(jnp.int32)
 
 
 def scored_intersect_batched(a: ScoredStack, b: ScoredStack, rest, th, *,
@@ -759,11 +423,20 @@ def scored_intersect_batched(a: ScoredStack, b: ScoredStack, rest, th, *,
 
     ``rest``/``th`` are int32[N]: the summed max impacts of the other
     live query terms in this segment, and the current top-k heap
-    threshold (-1 disables skipping — every bound is > -1).
+    threshold (-1 disables skipping — every bound is > -1).  The kernel
+    returns b's impact on each matched a-lane; the impact sum and the
+    block-max mask are elementwise jnp on its output.
     """
     assert a.ids.firsts.ndim == 2 and b.ids.firsts.ndim == 2, \
         "stack leaves must be [N, ...]; reshape the (Q, G) batch first"
     if a.ids.n_blocks == 0 or a.ids.firsts.shape[0] == 0:
         return jnp.zeros((a.ids.firsts.shape[0],
                           a.ids.n_blocks * SEG_BLOCK), jnp.int32)
-    return _scored_call_batched(a, b, rest, th, interpret=interpret)
+    matched = intersect_rows(decode_stacked(a.ids), decode_stacked(b.ids),
+                             decode_scores(b.swords), interpret=interpret)
+    keep = (jnp.asarray(a.bmax, jnp.int32)
+            + jnp.asarray(rest, jnp.int32)[:, None]
+            > jnp.asarray(th, jnp.int32)[:, None])
+    keep = jnp.repeat(keep, SEG_BLOCK, axis=1)
+    return jnp.where(keep & (matched > 0),
+                     decode_scores(a.swords) + matched, 0)

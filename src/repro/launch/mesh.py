@@ -12,18 +12,18 @@ Axis roles (DESIGN.md §5):
 """
 from __future__ import annotations
 
-import jax
+from repro.dist.collectives import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh for tests/elastic restore (divisor meshes)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return auto_mesh(shape, axes)
 
 
 def dp_extent(mesh) -> int:

@@ -103,3 +103,38 @@ def test_config_space_counts():
     # C(6,4) = 15 strictly-increasing 4-subsets of {0..5}
     assert len(cfgs) == 15
     assert all(len(c) == 4 and list(c) == sorted(c) for c in cfgs)
+
+
+def _slices_per_pool_loop(z, freqs, slack, start_pools):
+    """Term-by-term walk of the §3.3 progression (the per-pool slice
+    demand, written the obvious way)."""
+    P = len(z)
+    need = np.zeros(P, np.int64)
+    for f, p0 in zip(freqs, start_pools):
+        if f <= 0:
+            continue
+        remaining = int(f)
+        for p in range(int(p0), P):
+            cap = 2 ** z[p] - (1 if p > 0 else 0)
+            if p < P - 1:
+                need[p] += 1
+                remaining -= cap
+                if remaining <= 0:
+                    break
+            else:
+                need[p] += max(-(-remaining // cap), 1)
+                break
+    return tuple(int(x) for x in np.maximum((need * slack).astype(np.int64),
+                                            8))
+
+
+@pytest.mark.parametrize("z", [PROD, (0, 2, 5), (3,), (1, 2, 3, 4, 5)])
+@pytest.mark.parametrize("sp", [False, True])
+def test_slices_per_pool_matches_term_walk(z, sp):
+    rng = np.random.default_rng(len(z) + sp)
+    freqs = np.concatenate([rng.zipf(1.3, 2000) % 20000, [0, 1, 2, 3]])
+    starts = (rng.integers(0, len(z), freqs.size) if sp
+              else np.zeros(freqs.size, np.int64))
+    got = an.slices_per_pool(z, freqs, slack=1.3,
+                             start_pools=starts if sp else None)
+    assert got == _slices_per_pool_loop(z, freqs, 1.3, starts)

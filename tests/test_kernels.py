@@ -166,6 +166,79 @@ def test_intersect_mask_edges():
         np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 
+@pytest.mark.parametrize("ta,tb", [(128, 128), (256, 512)])
+def test_intersect_mask_under_vmap(ta, tb):
+    """Nested vmap (queries x shards, as the engines call it) folds into
+    the kernel's row grid; an unmapped operand is broadcast."""
+    a = np.stack([np.stack([_pad_asc(RNG.choice(3000, n, replace=False),
+                                     640) for n in (0, 300, 640)])
+                  for _ in range(2)])
+    b = np.stack([np.stack([_pad_asc(RNG.choice(3000, n, replace=False),
+                                     384) for n in (200, 0, 384)])
+                  for _ in range(2)])
+
+    def kern(x, y):
+        return ops.intersect_mask(x, y, ta=ta, tb=tb, interpret=True)
+
+    want = np.stack([
+        np.stack([np.isin(a[i, j], b[i, j]) & (a[i, j] != 0xFFFFFFFF)
+                  for j in range(3)]) for i in range(2)]).astype(np.int32)
+    got = jax.jit(jax.vmap(jax.vmap(kern)))(jnp.asarray(a), jnp.asarray(b))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    got = jax.vmap(kern, in_axes=(0, None))(jnp.asarray(a[0]),
+                                            jnp.asarray(b[0, 0]))
+    want = np.stack([np.isin(a[0, j], b[0, 0]) & (a[0, j] != 0xFFFFFFFF)
+                     for j in range(3)]).astype(np.int32)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("n,order", [(40, "random"), (2500, "random"),
+                                     (2500, "sorted")])
+def test_bulk_append_matches_oracle(n, order):
+    """The row read-modify-write scatter against the jnp oracle across its
+    edges: writes into a target's last row, a vocabulary that is not whole
+    rows, streams of one and of several SMEM chunks, skip lanes (address >= length), rows held across
+    consecutive writes, and rows revisited after a swap."""
+    from repro.core.slicepool import row_words
+    from repro.kernels.bulk_append import bulk_append
+    rng = np.random.default_rng(n)
+    H, V = row_words(2 * n + 77), n + 45    # a partial last vocab row
+    heap = rng.integers(0, 2**32, H, dtype=np.uint64).astype(np.uint32)
+    tail = rng.integers(0, 2**32, V, dtype=np.uint64).astype(np.uint32)
+    freq = rng.integers(0, 1000, V).astype(np.int32)
+    slots = rng.permutation(H + n // 4)[: 2 * n]    # post + ptr, disjoint
+    terms = rng.permutation(V + n // 8)[:n]
+    if order == "sorted":
+        slots.sort()
+        terms.sort()
+    args = [jnp.asarray(x) for x in (
+        heap, tail, freq,
+        slots[:n].astype(np.int32),
+        rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32),
+        slots[n:].astype(np.int32),
+        rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32),
+        terms.astype(np.int32),
+        rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32),
+        rng.integers(0, 1000, n).astype(np.int32))]
+    got = bulk_append(*args, interpret=True)
+    want = ref.bulk_append_ref(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_bulk_append_refuses_partial_heap_rows():
+    """The heap is allocated in whole 128-word rows
+    (``slicepool.row_words``); a partial row would need a padded copy of
+    the heap, so the kernel refuses it."""
+    from repro.kernels.bulk_append import bulk_append
+    z = jnp.zeros((1,), jnp.int32)
+    u = jnp.zeros((1,), jnp.uint32)
+    with pytest.raises(ValueError, match="whole 128-word rows"):
+        bulk_append(jnp.zeros((200,), jnp.uint32), jnp.zeros((100,), jnp.uint32),
+                    jnp.zeros((100,), jnp.int32), z, u, z, u, z, u, z,
+                    interpret=True)
+
+
 def test_intersect_mask_used_by_query_engine():
     """Kernel mask -> compaction reproduces intersect_asc."""
     from repro.core.query import _compact, intersect_asc

@@ -40,7 +40,7 @@ SCRIPT = textwrap.dedent("""
         p1, o1, m1 = jax.jit(step)(params, opt_state, toks)
 
         # 2x4 mesh, full rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = C.host_mesh((2, 4), ("data", "model"))
         rules = default_rules(mesh, fsdp=True)
         with mesh, use_rules(rules):
             p_sh = tree_shardings(rules, T.lm_param_specs(cfg))
@@ -61,7 +61,7 @@ SCRIPT = textwrap.dedent("""
 
     # logical-axis collectives: psum/all_gather through the rules table
     # must equal the plain jnp reductions.
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = C.host_mesh((2, 4), ("data", "model"))
     rules = default_rules(mesh, fsdp=True)
@@ -74,7 +74,7 @@ SCRIPT = textwrap.dedent("""
 
         tot, gathered = shard_map(
             body, mesh=mesh, in_specs=P("data", None),
-            out_specs=(P(), P()), check_rep=False)(x)
+            out_specs=(P(), P()), check_vma=False)(x)
         assert abs(float(tot) - float(jnp.sum(x))) < 1e-6
         np.testing.assert_array_equal(np.asarray(gathered), np.asarray(x))
         # unmapped logical name -> exact no-op
