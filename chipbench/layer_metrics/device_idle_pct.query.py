@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device,
+in a query cell: what the host's serving loop, gathers and copies
+cost the chip."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
