@@ -536,6 +536,7 @@ class _LifecycleBase:
             t1[:Q] = [p[0] for p in queries]
             t2[:Q] = [p[1] for p in queries]
             live = jnp.asarray((np.arange(Qb) < Q).astype(np.int32))
+            rows, slots = Qb, 2
             ad, an = (self._stub_active(Qb) if frozen_only
                       else self._active_batch(kind, t1, t2))
             if stack is None:
@@ -552,6 +553,7 @@ class _LifecycleBase:
             # batch must not pay for max_query_len slots of decode/fold
             tb = min(qexec.bucket_pow2(int(n_terms.max()), 1),
                      self.max_query_len)
+            rows, slots = terms.shape[0], tb
             ad, an = (self._stub_active(terms.shape[0]) if frozen_only
                       else self._active_batch(kind, terms, n_terms, tb))
             if stack is None:
@@ -568,7 +570,7 @@ class _LifecycleBase:
             out = [D[i, : int(N[i])].astype(np.int64) for i in range(Q)]
             return out if limit is None else [o[:limit] for o in out]
 
-        return qexec.Pending((desc, n), finish)
+        return qexec.Pending((desc, n), finish, rows, slots)
 
     def _batch_topk(self, queries: Sequence, k: int,
                     frozen_only: bool = False) -> List[np.ndarray]:
@@ -606,7 +608,7 @@ class _LifecycleBase:
             return [D[i, : min(int(N[i]), k)].astype(np.int64)
                     for i in range(Q)]
 
-        return qexec.Pending((desc, n), finish)
+        return qexec.Pending((desc, n), finish, terms.shape[0], tb)
 
     def conjunctive_batch(self, queries: Sequence[Sequence[int]],
                           limit: Optional[int] = None,
@@ -762,7 +764,8 @@ class _LifecycleBase:
                 inner = self._scored_batch_async(
                     queries, None, full=True, frozen_only=frozen_only)
                 return qexec.Pending(
-                    (), lambda: [(i[:k], s[:k]) for i, s in inner.wait()])
+                    (), lambda: [(i[:k], s[:k]) for i, s in inner.wait()],
+                    inner.rows, inner.slots)
         terms, n_terms = qexec.pad_query_batch(queries, self.max_query_len)
         tb = min(qexec.bucket_pow2(int(n_terms.max()), 1),
                  self.max_query_len)
@@ -791,7 +794,8 @@ class _LifecycleBase:
                          S[i, : int(N[i])].astype(np.int64)[:lim])
                         for i in range(Q)]
 
-            return qexec.Pending((ids, scs, n), finish_full)
+            return qexec.Pending((ids, scs, n), finish_full,
+                                 terms.shape[0], tb)
         k_pad = qexec.bucket_pow2(k, floor=8)
         if stack is None:
             ids, scs, n = qexec.finalize_scored(
@@ -802,7 +806,8 @@ class _LifecycleBase:
                          S[i, : min(int(N[i]), k)].astype(np.int64))
                         for i in range(Q)]
 
-            return qexec.Pending((ids, scs, n), finish_nostack)
+            return qexec.Pending((ids, scs, n), finish_nostack,
+                                 terms.shape[0], tb)
         sc, lasts, smax = stack.gather_scored(terms[:, :tb], n_terms)
         ids, scs, n, bskip, blive = qexec.frozen_scored_topk(
             ad, asc, an, sc, jnp.asarray(n_terms), base, lasts, smax,
@@ -817,7 +822,8 @@ class _LifecycleBase:
                      S[i, : min(int(N[i]), k)].astype(np.int64))
                     for i in range(Q)]
 
-        return qexec.Pending((ids, scs, n, bskip, blive), finish)
+        return qexec.Pending((ids, scs, n, bskip, blive), finish,
+                             terms.shape[0], tb)
 
     def _scored_unified(self, terms: Sequence[int],
                         k: Optional[int],

@@ -196,21 +196,25 @@ class FrozenStack:
         shared pow2 (NB, PW) bucket.  Host-side numpy; the single
         ``jnp.asarray`` per leaf is the only device transfer.
         """
-        cells = [[self._term_stack(int(t)) if j < int(n)
-                  else self._empty_stack()
-                  for j, t in enumerate(row)]
-                 for row, n in zip(terms, n_terms)]
-        nb = self._ratchet("nb", bucket_pow2(
-            max(c[0].n_blocks for row in cells for c in row)))
-        pw = self._ratchet("pw", bucket_pow2(
-            max(c[0].n_words for row in cells for c in row)))
-        rows = [[repad_stacked(c[0], nb, pw) for c in row] for row in cells]
-        leaves = StackedLists(*[
-            np.stack([np.stack([getattr(c, f) for c in row])
-                      for row in rows])
-            for f in StackedLists._fields])
-        lasts = np.stack([np.stack([c[1] for c in row]) for row in cells])
-        return (jax.tree.map(jnp.asarray, leaves), jnp.asarray(lasts))
+        with jax.profiler.TraceAnnotation("qexec.frozen_gather") as span:
+            cells = [[self._term_stack(int(t)) if j < int(n)
+                      else self._empty_stack()
+                      for j, t in enumerate(row)]
+                     for row, n in zip(terms, n_terms)]
+            nb = self._ratchet("nb", bucket_pow2(
+                max(c[0].n_blocks for row in cells for c in row)))
+            pw = self._ratchet("pw", bucket_pow2(
+                max(c[0].n_words for row in cells for c in row)))
+            rows = [[repad_stacked(c[0], nb, pw) for c in row]
+                    for row in cells]
+            leaves = StackedLists(*[
+                np.stack([np.stack([getattr(c, f) for c in row])
+                          for row in rows])
+                for f in StackedLists._fields])
+            lasts = np.stack([np.stack([c[1] for c in row])
+                              for row in cells])
+            span.set_metadata(bytes=_nbytes(leaves, lasts))
+            return (jax.tree.map(jnp.asarray, leaves), jnp.asarray(lasts))
 
     def gather_scored(self, terms: np.ndarray, n_terms: np.ndarray
                       ) -> Tuple[ScoredStack, jax.Array, jax.Array]:
@@ -219,27 +223,33 @@ class FrozenStack:
         smax int32[Q, T, G])`` — docid stacks plus impact planes,
         block-max planes and the per-(term, segment) max-impact summary.
         """
-        cells = [[self._scored_term(int(t)) if j < int(n)
-                  else self._empty_scored()
-                  for j, t in enumerate(row)]
-                 for row, n in zip(terms, n_terms)]
-        nb = self._ratchet("snb", bucket_pow2(
-            max(c[0].ids.n_blocks for row in cells for c in row)))
-        pw = self._ratchet("spw", bucket_pow2(
-            max(c[0].ids.n_words for row in cells for c in row)))
-        rows = [[repad_scored(c[0], nb, pw) for c in row] for row in cells]
-        ids = StackedLists(*[
-            np.stack([np.stack([getattr(c.ids, f) for c in row])
-                      for row in rows])
-            for f in StackedLists._fields])
-        swords = np.stack([np.stack([c.swords for c in row])
-                           for row in rows])
-        bmax = np.stack([np.stack([c.bmax for c in row]) for row in rows])
-        leaves = ScoredStack(ids=ids, swords=swords, bmax=bmax)
-        lasts = np.stack([np.stack([c[1] for c in row]) for row in cells])
-        smax = np.stack([np.stack([c[2] for c in row]) for row in cells])
-        return (jax.tree.map(jnp.asarray, leaves), jnp.asarray(lasts),
-                jnp.asarray(smax))
+        with jax.profiler.TraceAnnotation("qexec.frozen_gather") as span:
+            cells = [[self._scored_term(int(t)) if j < int(n)
+                      else self._empty_scored()
+                      for j, t in enumerate(row)]
+                     for row, n in zip(terms, n_terms)]
+            nb = self._ratchet("snb", bucket_pow2(
+                max(c[0].ids.n_blocks for row in cells for c in row)))
+            pw = self._ratchet("spw", bucket_pow2(
+                max(c[0].ids.n_words for row in cells for c in row)))
+            rows = [[repad_scored(c[0], nb, pw) for c in row]
+                    for row in cells]
+            ids = StackedLists(*[
+                np.stack([np.stack([getattr(c.ids, f) for c in row])
+                          for row in rows])
+                for f in StackedLists._fields])
+            swords = np.stack([np.stack([c.swords for c in row])
+                               for row in rows])
+            bmax = np.stack([np.stack([c.bmax for c in row])
+                             for row in rows])
+            leaves = ScoredStack(ids=ids, swords=swords, bmax=bmax)
+            lasts = np.stack([np.stack([c[1] for c in row])
+                              for row in cells])
+            smax = np.stack([np.stack([c[2] for c in row])
+                             for row in cells])
+            span.set_metadata(bytes=_nbytes(leaves, lasts, smax))
+            return (jax.tree.map(jnp.asarray, leaves), jnp.asarray(lasts),
+                    jnp.asarray(smax))
 
     def gather_postings(self, t1s: np.ndarray, t2s: np.ndarray,
                         n_live: Optional[int] = None
@@ -252,22 +262,30 @@ class FrozenStack:
         shared width bucket or ships discarded data."""
         if n_live is None:
             n_live = len(t1s)
-        empty = np.full((self.n_segments, 8), INVALID, np.uint32)
-        p1 = [self._post_stack(int(t)) if i < n_live else empty
-              for i, t in enumerate(t1s)]
-        p2 = [self._post_stack(int(t)) if i < n_live else empty
-              for i, t in enumerate(t2s)]
-        width = self._ratchet("pl", bucket_pow2(
-            max(a.shape[1] for a in p1 + p2)))
+        with jax.profiler.TraceAnnotation("qexec.frozen_gather") as span:
+            empty = np.full((self.n_segments, 8), INVALID, np.uint32)
+            p1 = [self._post_stack(int(t)) if i < n_live else empty
+                  for i, t in enumerate(t1s)]
+            p2 = [self._post_stack(int(t)) if i < n_live else empty
+                  for i, t in enumerate(t2s)]
+            width = self._ratchet("pl", bucket_pow2(
+                max(a.shape[1] for a in p1 + p2)))
 
-        def pad(stacks):
-            out = np.full((len(stacks), self.n_segments, width), INVALID,
-                          np.uint32)
-            for i, a in enumerate(stacks):
-                out[i, :, : a.shape[1]] = a
-            return jnp.asarray(out)
+            def pad(stacks):
+                out = np.full((len(stacks), self.n_segments, width),
+                              INVALID, np.uint32)
+                for i, a in enumerate(stacks):
+                    out[i, :, : a.shape[1]] = a
+                return out
 
-        return pad(p1), pad(p2)
+            h1, h2 = pad(p1), pad(p2)
+            span.set_metadata(bytes=h1.nbytes + h2.nbytes)
+            return jnp.asarray(h1), jnp.asarray(h2)
+
+
+def _nbytes(*trees) -> int:
+    """Bytes of the host (numpy) leaves a gather ships to the device."""
+    return sum(x.nbytes for x in jax.tree.leaves(trees))
 
 
 # ---------------------------------------------------------------------------
@@ -890,15 +908,21 @@ class Pending:
     their host (numpy) values and builds the per-query python result —
     the same structure the synchronous engine method returns.  ``wait``
     is idempotent and drops the device arrays after the first call.
+    ``rows`` x ``slots`` is the padded term matrix the engine evaluated
+    the batch at (pow2 query rows, pow2 term-slot bucket; a phrase
+    batch has 2 slots); both are 0 where no batched evaluation ran (an
+    empty batch, or the per-query oracle of ``batched=False``).
     """
 
-    __slots__ = ("_arrays", "_finish", "_done", "_result")
+    __slots__ = ("_arrays", "_finish", "_done", "_result", "rows", "slots")
 
-    def __init__(self, arrays, finish):
+    def __init__(self, arrays, finish, rows: int = 0, slots: int = 0):
         self._arrays = tuple(arrays)
         self._finish = finish
         self._done = False
         self._result = None
+        self.rows = rows
+        self.slots = slots
 
     @property
     def done(self) -> bool:
@@ -906,10 +930,13 @@ class Pending:
 
     def wait(self):
         if not self._done:
-            host = [np.asarray(a) for a in self._arrays]
+            with jax.profiler.TraceAnnotation("qexec.sync") as span:
+                host = [np.asarray(a) for a in self._arrays]
+                span.set_metadata(bytes=sum(h.nbytes for h in host))
             self._arrays = ()
             finish, self._finish = self._finish, None
-            self._result = finish(*host)
+            with jax.profiler.TraceAnnotation("qexec.finish"):
+                self._result = finish(*host)
             self._done = True
         return self._result
 

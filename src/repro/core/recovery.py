@@ -54,6 +54,7 @@ import struct
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -463,8 +464,10 @@ class IngestJournal:
         ``fsync=True``, an OS crash or power loss)."""
         docs = np.ascontiguousarray(np.asarray(docs))
         seq = self.next_seq
-        self._f.write(_pack_record(seq, docs))
-        self._flush()
+        with jax.profiler.TraceAnnotation("journal.append", seq=seq,
+                                          bytes=docs.nbytes):
+            self._f.write(_pack_record(seq, docs))
+            self._flush()
         self.next_seq += 1
         return seq
 

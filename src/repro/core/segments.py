@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import postings as post
@@ -405,20 +406,23 @@ class SegmentSet:
         and must be safe to call unconditionally."""
         if self.active.next_docid == 0:
             return None
-        fz = freeze(self.active, doc_base=self._doc_base)
-        # H(t) snapshot: the freqs of THIS rollover, taken before any
-        # compaction can merge the segment into a multi-rollover tier
-        # (history_freqs must keep meaning "the last rollover").
-        self._hist_freqs = fz.term_freqs()
-        self.frozen.append(fz)
-        self.n_rollovers += 1
-        if len(self.frozen) > self.max_segments - 1:
-            self.frozen.pop(0)  # oldest segment retired (paper: bounded set)
-        self._doc_base += self.active.next_docid
-        released = slicepool.release_slices(
-            self.layout, self.active.state, fz.freed_slices)
-        self.active = self._new_active(state=released)
-        self._apply_compaction()
+        with jax.profiler.TraceAnnotation("segments.rollover",
+                                          docs=self.active.next_docid):
+            fz = freeze(self.active, doc_base=self._doc_base)
+            # H(t) snapshot: the freqs of THIS rollover, taken before any
+            # compaction can merge the segment into a multi-rollover tier
+            # (history_freqs must keep meaning "the last rollover").
+            self._hist_freqs = fz.term_freqs()
+            self.frozen.append(fz)
+            self.n_rollovers += 1
+            if len(self.frozen) > self.max_segments - 1:
+                # oldest segment retired (paper: bounded set)
+                self.frozen.pop(0)
+            self._doc_base += self.active.next_docid
+            released = slicepool.release_slices(
+                self.layout, self.active.state, fz.freed_slices)
+            self.active = self._new_active(state=released)
+            self._apply_compaction()
         return fz
 
     def compact(self, k: int, *, start: int = 0
@@ -434,9 +438,10 @@ class SegmentSet:
         k = min(int(k), len(self.frozen) - start)
         if k < 2:
             return None
-        merged = merge_frozen(self.frozen[start: start + k])
-        self.frozen[start: start + k] = [merged]
-        self.n_compactions += 1
+        with jax.profiler.TraceAnnotation("segments.compact", k=k):
+            merged = merge_frozen(self.frozen[start: start + k])
+            self.frozen[start: start + k] = [merged]
+            self.n_compactions += 1
         return merged
 
     def _apply_compaction(self) -> None:

@@ -59,6 +59,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core import qexec, slicepool
@@ -106,8 +107,10 @@ class QueryResponse:
     level: int                  # degradation ladder rung served at
     level_name: str
     degraded: bool              # level > 0 (always flagged)
-    latency_s: float
+    latency_s: float            # queued_s + service_s
     deadline_met: bool
+    queued_s: float             # acceptance to the batch's flush
+    service_s: float            # flush to response
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,6 +159,10 @@ class ServeStats:
     flushes_full: int = 0          # bucket filled
     flushes_timer: int = 0         # batch-deadline timer fired
     batches_dispatched: int = 0
+    # padded query rows x term slots the engine evaluated, against the
+    # term count of the live queries: the coalescer's padding cost
+    query_cells_dispatched: int = 0
+    query_terms_live: int = 0
     rejections_without_retry_after: int = 0   # invariant: stays 0
     ingest_submitted: int = 0
     ingest_rejected: int = 0       # backpressure before the ack
@@ -173,8 +180,8 @@ class ServeLoop:
     something drives :meth:`step` (a thread, an event loop, a bench's
     while-loop), responses come back from :meth:`take_responses`.
 
-    ``clock`` is injectable (tests pass a manual clock; the bench uses
-    ``time.monotonic``).  ``journal`` (an
+    ``clock`` is injectable (tests pass a manual clock; ``chipbench``
+    passes ``time.perf_counter``).  ``journal`` (an
     :class:`~repro.core.recovery.IngestJournal`) makes the ingest ack
     durable: append happens inside :meth:`submit_ingest` BEFORE the seq
     is returned, so every acknowledged batch survives a crash and
@@ -302,8 +309,9 @@ class ServeLoop:
         if len(self._ingest_q) >= self.config.ingest_queue_cap:
             return self._reject("ingest_queue_full", len(self._ingest_q),
                                 is_query=False)
-        util = slicepool.pool_utilization(
-            self.engine.layout, self.engine.segments.active.state)
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            util = slicepool.pool_utilization(
+                self.engine.layout, self.engine.segments.active.state)
         if util >= self.config.ingest_reject_util:
             return self._reject("pool_pressure", len(self._ingest_q),
                                 is_query=False)
@@ -328,7 +336,7 @@ class ServeLoop:
         self._dispatch_ingest()        # overlaps the waits below
         produced = 0
         for pend, rqs, level in in_flight:
-            produced += self._collect(pend, rqs, level)
+            produced += self._collect(pend, rqs, level, now)
         return produced
 
     def drain(self, max_steps: int = 100_000) -> List[QueryResponse]:
@@ -395,13 +403,16 @@ class ServeLoop:
             self.stats.flushes_timer += 1
         take = self._query_q[:cfg.max_batch]
         del self._query_q[:cfg.max_batch]
-        level = self.degradation_level()
-        groups: Dict[tuple, List[QueryRequest]] = {}
-        for rq in take:
-            groups.setdefault(self._plan(rq, level), []).append(rq)
-        out = []
-        for spec, rqs in groups.items():
-            out.append((self._dispatch_group(spec, rqs), rqs, level))
+        with jax.profiler.TraceAnnotation("serve.flush",
+                                          queries=len(take)) as span:
+            level = self.degradation_level()
+            groups: Dict[tuple, List[QueryRequest]] = {}
+            for rq in take:
+                groups.setdefault(self._plan(rq, level), []).append(rq)
+            span.set_metadata(groups=len(groups), level=level)
+            out = []
+            for spec, rqs in groups.items():
+                out.append((self._dispatch_group(spec, rqs), rqs, level))
         self.stats.batches_dispatched += len(groups)
         self._n_in_flight += len(take)
         return out
@@ -429,11 +440,19 @@ class ServeLoop:
                         rqs: List[QueryRequest]) -> qexec.Pending:
         mode, kk, frozen_only = spec
         queries = [rq.terms for rq in rqs]
-        if mode in ("topk", "scored", "scored_full"):
-            return self.engine.dispatch(mode, queries, k=kk,
-                                        frozen_only=frozen_only)
-        return self.engine.dispatch(mode, queries, limit=kk,
-                                    frozen_only=frozen_only)
+        with jax.profiler.TraceAnnotation("serve.dispatch", qid=rqs[0].qid,
+                                          queries=len(rqs)) as span:
+            if mode in ("topk", "scored", "scored_full"):
+                pend = self.engine.dispatch(mode, queries, k=kk,
+                                            frozen_only=frozen_only)
+            else:
+                pend = self.engine.dispatch(mode, queries, limit=kk,
+                                            frozen_only=frozen_only)
+            span.set_metadata(rows=pend.rows, slots=pend.slots)
+        if pend.rows:
+            self.stats.query_cells_dispatched += pend.rows * pend.slots
+            self.stats.query_terms_live += sum(len(q) for q in queries)
+        return pend
 
     def _dispatch_ingest(self) -> None:
         if not self._ingest_q:
@@ -442,7 +461,9 @@ class ServeLoop:
         # escapes mid-ingest the batch stays queued, so resume_with can
         # account for it as replay-recovered instead of losing it.
         seq, docs = self._ingest_q[0]
-        ok = self.engine.ingest(docs)
+        with jax.profiler.TraceAnnotation("serve.ingest", seq=seq,
+                                          docs=int(docs.shape[0])):
+            ok = self.engine.ingest(docs)
         self._ingest_q.pop(0)
         self._applied_seq = seq + 1
         if ok:
@@ -455,33 +476,37 @@ class ServeLoop:
             self.stats.ingest_shed += 1
 
     def _collect(self, pend: qexec.Pending, rqs: List[QueryRequest],
-                 level: int) -> int:
-        results = pend.wait()
-        done = self.clock()
-        for rq, res in zip(rqs, results):
-            if isinstance(res, tuple):
-                docids, scores = res
-            else:
-                docids, scores = res, None
-            if level == DEGRADE_NONE and rq.kind == "topk":
-                docids = docids[: rq.k]
-            latency = done - rq.submitted_s
-            met = done <= rq.deadline_s
-            if not met:
-                self.stats.deadline_misses += 1
-            a = self.config.latency_alpha
-            if self.stats.queries_served == 0:
-                self.stats.latency_ewma_s = latency
-            else:
-                self.stats.latency_ewma_s = \
-                    (1.0 - a) * self.stats.latency_ewma_s + a * latency
-            self.stats.queries_served += 1
-            self.stats.served_by_level[level] += 1
-            self._responses.append(QueryResponse(
-                qid=rq.qid, kind=rq.kind, docids=docids, scores=scores,
-                level=level, level_name=LEVEL_NAMES[level],
-                degraded=level > DEGRADE_NONE, latency_s=latency,
-                deadline_met=met))
+                 level: int, flushed_s: float) -> int:
+        with jax.profiler.TraceAnnotation("serve.collect", qid=rqs[0].qid,
+                                          queries=len(rqs)):
+            results = pend.wait()
+            done = self.clock()
+            service = done - flushed_s
+            for rq, res in zip(rqs, results):
+                if isinstance(res, tuple):
+                    docids, scores = res
+                else:
+                    docids, scores = res, None
+                if level == DEGRADE_NONE and rq.kind == "topk":
+                    docids = docids[: rq.k]
+                queued = flushed_s - rq.submitted_s
+                latency = queued + service
+                met = done <= rq.deadline_s
+                if not met:
+                    self.stats.deadline_misses += 1
+                a = self.config.latency_alpha
+                if self.stats.queries_served == 0:
+                    self.stats.latency_ewma_s = latency
+                else:
+                    self.stats.latency_ewma_s = \
+                        (1.0 - a) * self.stats.latency_ewma_s + a * latency
+                self.stats.queries_served += 1
+                self.stats.served_by_level[level] += 1
+                self._responses.append(QueryResponse(
+                    qid=rq.qid, kind=rq.kind, docids=docids, scores=scores,
+                    level=level, level_name=LEVEL_NAMES[level],
+                    degraded=level > DEGRADE_NONE, latency_s=latency,
+                    deadline_met=met, queued_s=queued, service_s=service))
         self._n_in_flight -= len(rqs)
         return len(rqs)
 

@@ -519,34 +519,37 @@ class ShardedSegmentSet:
         :meth:`~repro.core.segments.SegmentSet.rollover`."""
         if self.active.next_docid == 0:
             return None
-        seg = self.active
-        S = seg.num_shards
-        heap = np.asarray(seg.state.heap)
-        tail = np.asarray(seg.state.tail)
-        freq = np.asarray(seg.state.freq)
-        local_docs = seg.next_docid // S
-        shards = [
-            seg_mod.freeze_state(
-                self.layout, heap[s], tail[s], freq[s],
-                n_docs=local_docs, doc_base=self._doc_base,
-                docid_map=lambda ids, s=s: ids * np.uint32(S) + np.uint32(s))
-            for s in range(S)
-        ]
-        fz = ShardedFrozenSegment(shards, n_docs=seg.next_docid,
-                                  doc_base=self._doc_base)
-        # H(t) snapshot: the freqs of THIS rollover, taken before any
-        # compaction can merge the segment into a multi-rollover tier
-        # (history_freqs must keep meaning "the last rollover").
-        self._hist_freqs = fz.term_freqs()
-        self.frozen.append(fz)
-        self.n_rollovers += 1
-        if len(self.frozen) > self.max_segments - 1:
-            self.frozen.pop(0)  # oldest segment retired (bounded set)
-        self._doc_base += seg.next_docid
-        released = slicepool.release_slices(
-            self.layout, seg.state, [sh.freed_slices for sh in shards])
-        self.active = self._new_active(state=released)
-        self._apply_compaction()
+        with jax.profiler.TraceAnnotation("segments.rollover",
+                                          docs=self.active.next_docid):
+            seg = self.active
+            S = seg.num_shards
+            heap = np.asarray(seg.state.heap)
+            tail = np.asarray(seg.state.tail)
+            freq = np.asarray(seg.state.freq)
+            local_docs = seg.next_docid // S
+            shards = [
+                seg_mod.freeze_state(
+                    self.layout, heap[s], tail[s], freq[s],
+                    n_docs=local_docs, doc_base=self._doc_base,
+                    docid_map=lambda ids, s=s: (ids * np.uint32(S)
+                                                + np.uint32(s)))
+                for s in range(S)
+            ]
+            fz = ShardedFrozenSegment(shards, n_docs=seg.next_docid,
+                                      doc_base=self._doc_base)
+            # H(t) snapshot: the freqs of THIS rollover, taken before any
+            # compaction can merge the segment into a multi-rollover tier
+            # (history_freqs must keep meaning "the last rollover").
+            self._hist_freqs = fz.term_freqs()
+            self.frozen.append(fz)
+            self.n_rollovers += 1
+            if len(self.frozen) > self.max_segments - 1:
+                self.frozen.pop(0)  # oldest segment retired (bounded set)
+            self._doc_base += seg.next_docid
+            released = slicepool.release_slices(
+                self.layout, seg.state, [sh.freed_slices for sh in shards])
+            self.active = self._new_active(state=released)
+            self._apply_compaction()
         return fz
 
     def compact(self, k: int, *, start: int = 0
@@ -563,20 +566,21 @@ class ShardedSegmentSet:
         k = min(int(k), len(self.frozen) - start)
         if k < 2:
             return None
-        window = self.frozen[start: start + k]
-        base, n_docs, offs = seg_mod._adjacent_window(window)
-        tier = max(int(fz.tier) for fz in window) + 1
-        S = len(window[0].shards)
-        shards = [
-            seg_mod._merge_csr([fz.shards[s] for fz in window], offs,
-                               n_docs=n_docs // S, doc_base=base,
-                               tier=tier)
-            for s in range(S)
-        ]
-        merged = ShardedFrozenSegment(shards, n_docs=n_docs,
-                                      doc_base=base, tier=tier)
-        self.frozen[start: start + k] = [merged]
-        self.n_compactions += 1
+        with jax.profiler.TraceAnnotation("segments.compact", k=k):
+            window = self.frozen[start: start + k]
+            base, n_docs, offs = seg_mod._adjacent_window(window)
+            tier = max(int(fz.tier) for fz in window) + 1
+            S = len(window[0].shards)
+            shards = [
+                seg_mod._merge_csr([fz.shards[s] for fz in window], offs,
+                                   n_docs=n_docs // S, doc_base=base,
+                                   tier=tier)
+                for s in range(S)
+            ]
+            merged = ShardedFrozenSegment(shards, n_docs=n_docs,
+                                          doc_base=base, tier=tier)
+            self.frozen[start: start + k] = [merged]
+            self.n_compactions += 1
         return merged
 
     def _apply_compaction(self) -> None:
