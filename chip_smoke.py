@@ -340,7 +340,7 @@ def _run(comp: CompileLog, *, devices, chips: int = 1,
             # the active program run here is the one the batch reuses
             ing_txt, query_txt = kernel_programs(
                 engine, docs, [terms for kind, terms, _ in queries
-                               if kind in ("conjunctive", "topk")])
+                               if kind == "conjunctive"])
         ts = time.perf_counter()
         rolled = engine.stats.rollovers
         loop.step()

@@ -592,9 +592,11 @@ class _LifecycleBase:
                  self.max_query_len)
         base = self._base_u32()
         k_pad = qexec.bucket_pow2(k, floor=8)
-        ad, an = (self._stub_active(terms.shape[0]) if frozen_only
-                  else self._active_topk_batch(terms, n_terms, k, k_pad,
-                                               tb))
+        if frozen_only:
+            (ad, an), tiles = self._stub_active(terms.shape[0]), None
+        else:
+            ad, an, tiles = self._active_topk_batch(terms, n_terms, k,
+                                                    k_pad, tb)
         stack = self._frozen_stack()
         if stack is None:
             desc, n = qexec.finalize(ad, an, jnp.asarray(n_terms), base)
@@ -608,7 +610,8 @@ class _LifecycleBase:
             return [D[i, : min(int(N[i]), k)].astype(np.int64)
                     for i in range(Q)]
 
-        return qexec.Pending((desc, n), finish, terms.shape[0], tb)
+        return qexec.Pending((desc, n), finish, terms.shape[0], tb,
+                             tiles=tiles, live_rows=Q)
 
     def conjunctive_batch(self, queries: Sequence[Sequence[int]],
                           limit: Optional[int] = None,
@@ -969,8 +972,10 @@ class LifecycleEngine(_LifecycleBase):
 
     def _active_topk_batch(self, terms, n_terms, k: int, k_pad: int,
                            tb: int):
+        """-> ``(desc, n, tiles)``: the early-exit active top-k, and the
+        driver tiles each row scanned."""
         fn = qexec.make_active_topk_fn(self.layout, self.max_slices,
-                                       self.max_len, tb, k_pad)
+                                       self.max_len, k_pad)
         return fn(self.segments.active.state, jnp.asarray(terms[:, :tb]),
                   jnp.asarray(n_terms), jnp.int32(min(k, k_pad)))
 
@@ -1060,8 +1065,9 @@ class ShardedLifecycleEngine(_LifecycleBase):
         # tile-level early exit inside shard_map is not implemented for
         # the sharded active pool; the full batched evaluation feeds the
         # frozen while_loop, which still early-exits across segments.
+        # No tile counter: the full evaluation scans no tiles.
         desc, n = self._active_batch("conjunctive", terms, n_terms, tb)
-        return desc, jnp.minimum(n, jnp.int32(k))
+        return desc, jnp.minimum(n, jnp.int32(k)), None
 
     def _active_scored_batch(self, terms, n_terms, _tb: int):
         # full max_query_len width, like _active_batch: the shard_map

@@ -30,8 +30,9 @@ query-side counterpart of bulk ingest, in three layers:
      newest-segment-first in a ``lax.while_loop`` and stops consuming
      older segments once ``k`` hits are collected;
      :func:`make_active_topk_fn` does the same inside the active
-     materializer, consuming the driving term's slice chain in
-     newest-first tiles.  Both are BIT-IDENTICAL to the full
+     segment, consuming the query's shortest slice chain in
+     newest-first tiles and probing the other terms' chains in place.
+     Both are BIT-IDENTICAL to the full
      evaluation's top-k (segments own disjoint descending docid
      ranges; tiles are consumed in docid-descending order), proven in
      tests/test_qexec.py for every k including k > |result|.
@@ -783,70 +784,80 @@ def make_active_scored_fn(layout: PoolLayout, max_slices: int,
 
 @functools.lru_cache(maxsize=slicepool.FACTORY_CACHE_SIZE)
 def make_active_topk_fn(layout: PoolLayout, max_slices: int, max_len: int,
-                        max_query_len: int = 8, k_pad: int = 8,
-                        tile: int = 128):
-    """Early-exit top-k over the ACTIVE segment: the driving term's
-    slice chain is consumed in newest-first tiles (the materializer's
-    reverse-chronological order IS descending docid order), each tile's
-    docids membership-tested against the other terms' lists, hits
-    banked — and the loop stops materialising older slice-chain tiles
-    once ``k`` hits are banked.  Bit-identical to
-    ``QueryEngine.topk_conjunctive`` (the full-intersection oracle):
-    hits surface in exactly the full evaluation's descending order.
+                        k_pad: int = 8, tile: int = 128):
+    """Early-exit top-k over the ACTIVE segment, driven by each row's
+    shortest list.  Every live term slot's chain is walked and windowed
+    (its newest ``max_len`` postings, as the materializer truncates);
+    the slot with the smallest window drives (ties: the lowest slot).
+    The driver's chain is consumed in newest-first tiles (the
+    materializer's reverse-chronological order IS descending docid
+    order); each tile's docids are probed for in the other slots' chains
+    in place (:func:`slicepool.make_chain_prober`) and count only if
+    found inside that slot's window; hits are banked, and the loop stops
+    once ``k`` are banked.  Nothing ``max_len`` lanes wide is built.
+    Bit-identical to ``QueryEngine.topk_conjunctive`` (the
+    full-intersection oracle): hits surface in exactly the full
+    evaluation's descending order.
 
     Returns a jitted ``f(state, terms[Q, T], n_terms[Q], k) ->
-    (desc uint32[Q, k_pad], n int32[Q])`` with SEGMENT-RELATIVE docids
-    (``frozen_topk`` globalises).  ``k`` is dynamic up to ``k_pad``.
+    (desc uint32[Q, k_pad], n int32[Q], tiles int32[Q])`` with
+    SEGMENT-RELATIVE docids (``frozen_topk`` globalises); ``tiles`` is
+    how many driver tiles each row scanned (0 for padding rows and for
+    rows with an empty list).  ``k`` is dynamic up to ``k_pad``.
     """
     tile = min(tile, max_len)
-    n_tiles = -(-max_len // tile)  # ceil: the ragged last tile still
-    #                                materializes (j < total masks it)
-    eng = q.make_engine(layout, max_slices, max_len, max_query_len)
     walk = slicepool.make_chain_walker(layout, max_slices)
+    probe = slicepool.make_chain_prober(layout, max_slices)
 
     @jax.jit
     def run(state, terms, n_terms, k):
+        heap = state.heap
+
         def one(trow, nt):
-            ids, _ = jax.vmap(lambda t: eng.docids_asc(state, t))(trow)
-            bases, starts, lasts, nsl = walk(state, trow[0])
-            cum = slicepool.chain_lens_cum(starts, lasts, nsl, max_slices)
-            total = jnp.minimum(cum[-1], max_len)
-            k_eff = jnp.where(nt > 0, k, 0)
+            bases, starts, lasts, nsl = jax.vmap(
+                lambda t: walk(state, t))(trow)            # [T, S], [T]
+            cum = jax.vmap(lambda s, l, n: slicepool.chain_lens_cum(
+                s, l, n, max_slices))(starts, lasts, nsl)
+            firsts = jax.vmap(lambda b, s, n: slicepool.chain_first_docids(
+                heap, b, s, n, max_slices))(bases, starts, nsl)
+            live = jnp.arange(trow.shape[0]) < nt
+            win = jnp.minimum(cum[:, -1], max_len)
+            drv = jnp.argmin(jnp.where(live, win, max_len + 1))
+            total = jnp.where(nt > 0, win[drv], 0)
             out0 = jnp.full((k_pad,), INVALID, jnp.uint32)
 
             def cond(c):
                 ti, b, _, _ = c
-                return (ti < n_tiles) & (b < k_eff) & (ti * tile < total)
+                return (b < k) & (ti * tile < total)
 
             def body(c):
                 ti, b, prev, out = c
-                # materialize ONE newest-first tile of the driving
-                # term's chain — the materializer's own address math
+                # materialize ONE newest-first tile of the driver's chain
+                # — the materializer's own address math
                 # (slicepool.chain_window_addrs), restricted to lanes
                 # [ti * tile, (ti + 1) * tile).
                 j = ti * tile + jnp.arange(tile, dtype=jnp.int32)
-                addr = slicepool.chain_window_addrs(bases, lasts, cum, j,
-                                                    max_slices)
-                vals = state.heap[addr]
-                d = jnp.where(j < total, post.docid(vals),
+                addr = slicepool.chain_window_addrs(
+                    bases[drv], lasts[drv], cum[drv], j, max_slices)
+                d = jnp.where(j < total, post.docid(heap[addr]),
                               jnp.uint32(INVALID))
                 prev_lane = jnp.concatenate([prev[None], d[:-1]])
                 keep = (d != INVALID) & (d != prev_lane)  # dedup positions
-                hit = keep
-                for jj in range(1, max_query_len):
-                    m = q.member_asc(d, ids[jj])
-                    hit = hit & jnp.where(jj < nt, m, True)
+                lane, found = jax.vmap(
+                    lambda *ch: probe(heap, *ch, d))(
+                        bases, starts, lasts, cum, firsts, nsl)  # [T, tile]
+                member = found & (lane < win[:, None])
+                hit = keep & jnp.all(member | ~live[:, None], axis=0)
                 comp, n_t = q._compact(d, hit)  # descending, hits first
-                lane = jnp.arange(tile)
-                idx = jnp.where(lane < n_t, b + lane, k_pad)
+                lanes = jnp.arange(tile)
+                idx = jnp.where(lanes < n_t, b + lanes, k_pad)
                 out = out.at[idx].set(comp, mode="drop")
-                return (ti + 1, jnp.minimum(k_eff, b + n_t),
-                        d[tile - 1], out)
+                return (ti + 1, jnp.minimum(k, b + n_t), d[tile - 1], out)
 
-            _, b, _, out = jax.lax.while_loop(
+            ti, b, _, out = jax.lax.while_loop(
                 cond, body,
                 (jnp.int32(0), jnp.int32(0), jnp.uint32(INVALID), out0))
-            return out, b
+            return out, b, ti
 
         return jax.vmap(one)(terms, n_terms)
 
@@ -912,17 +923,26 @@ class Pending:
     the batch at (pow2 query rows, pow2 term-slot bucket; a phrase
     batch has 2 slots); both are 0 where no batched evaluation ran (an
     empty batch, or the per-query oracle of ``batched=False``).
+    ``tiles`` (device int32[rows], or None) is the driver tiles the
+    active early-exit top-k scanned per row, fetched by the same sync:
+    after :meth:`wait`, ``topk_tiles`` is their sum and ``topk_rows``
+    the ``live_rows`` they were scanned for (both 0 without ``tiles``).
     """
 
-    __slots__ = ("_arrays", "_finish", "_done", "_result", "rows", "slots")
+    __slots__ = ("_arrays", "_finish", "_done", "_result", "rows", "slots",
+                 "_tiles", "topk_rows", "topk_tiles")
 
-    def __init__(self, arrays, finish, rows: int = 0, slots: int = 0):
+    def __init__(self, arrays, finish, rows: int = 0, slots: int = 0,
+                 tiles=None, live_rows: int = 0):
         self._arrays = tuple(arrays)
         self._finish = finish
         self._done = False
         self._result = None
         self.rows = rows
         self.slots = slots
+        self._tiles = tiles
+        self.topk_rows = live_rows if tiles is not None else 0
+        self.topk_tiles = 0
 
     @property
     def done(self) -> bool:
@@ -932,8 +952,12 @@ class Pending:
         if not self._done:
             with jax.profiler.TraceAnnotation("qexec.sync") as span:
                 host = [np.asarray(a) for a in self._arrays]
+                tiles = (None if self._tiles is None
+                         else np.asarray(self._tiles))
                 span.set_metadata(bytes=sum(h.nbytes for h in host))
-            self._arrays = ()
+            if tiles is not None:
+                self.topk_tiles = int(tiles.sum())
+            self._arrays, self._tiles = (), None
             finish, self._finish = self._finish, None
             with jax.profiler.TraceAnnotation("qexec.finish"):
                 self._result = finish(*host)

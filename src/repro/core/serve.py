@@ -67,8 +67,10 @@ from repro.core import qexec, slicepool
 # Degradation ladder: every query is served at exactly one level, and
 # the response carries it.  docs/serving.md tabulates the exactness
 # contract per rung; tests/test_serve.py proves each one.
-DEGRADE_NONE = 0         # exhaustive evaluation, results exact
-DEGRADE_EARLY_EXIT = 1   # early-exit top-k at the requested k
+DEGRADE_NONE = 0         # exhaustive evaluation, results exact (a
+#                          topk request's early exit IS full[:k])
+DEGRADE_EARLY_EXIT = 1   # early-exit top-k at the requested k, for
+#                          conjunctive requests too
 DEGRADE_REDUCED_K = 2    # early-exit at k // reduced_k_factor
 DEGRADE_FROZEN_ONLY = 3  # frozen segments only (active dispatch skipped)
 LEVEL_NAMES = ("exhaustive", "early_exit", "reduced_k", "frozen_only")
@@ -163,6 +165,10 @@ class ServeStats:
     # term count of the live queries: the coalescer's padding cost
     query_cells_dispatched: int = 0
     query_terms_live: int = 0
+    # how far the active early-exit top-k read its driving lists: tiles
+    # scanned, and the query rows it scanned them for
+    topk_tiles_scanned: int = 0
+    topk_rows_live: int = 0
     rejections_without_retry_after: int = 0   # invariant: stays 0
     ingest_submitted: int = 0
     ingest_rejected: int = 0       # backpressure before the ack
@@ -423,7 +429,7 @@ class ServeLoop:
         coalesce into one engine dispatch."""
         if level == DEGRADE_NONE:
             if rq.kind == "topk":
-                return ("conjunctive", None, False)  # full, sliced later
+                return ("topk", rq.k, False)  # early exit == full[:k]
             if rq.kind == "scored":
                 return ("scored_full", rq.k, False)
             return (rq.kind, None, False)
@@ -481,14 +487,14 @@ class ServeLoop:
                                           queries=len(rqs)):
             results = pend.wait()
             done = self.clock()
+            self.stats.topk_tiles_scanned += pend.topk_tiles
+            self.stats.topk_rows_live += pend.topk_rows
             service = done - flushed_s
             for rq, res in zip(rqs, results):
                 if isinstance(res, tuple):
                     docids, scores = res
                 else:
                     docids, scores = res, None
-                if level == DEGRADE_NONE and rq.kind == "topk":
-                    docids = docids[: rq.k]
                 queued = flushed_s - rq.submitted_s
                 latency = queued + service
                 met = done <= rq.deadline_s

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pointers as ptr_mod
+from repro.core import postings as post
 from repro.core.pointers import NULL, PoolLayout
 from repro.kernels.bulk_append import ROW
 
@@ -672,6 +673,50 @@ def chain_window_addrs(bases, lasts, cum, lanes, max_slices: int):
     before = jnp.where(s > 0, cum[jnp.maximum(s - 1, 0)], 0)
     within = (lanes - before).astype(jnp.uint32)
     return bases[s] + lasts[s] - within
+
+
+def chain_first_docids(heap, bases, starts, n_slices, max_slices: int):
+    """Docid of each walked slice's oldest posting (0 past the chain).
+    A chain runs newest slice first and docids only grow as postings are
+    appended, so these never rise along the chain: the probe's slice
+    index."""
+    live = jnp.arange(max_slices) < n_slices
+    return jnp.where(live, post.docid(heap[bases + starts]), 0)
+
+
+def make_chain_prober(layout: PoolLayout, max_slices: int):
+    """Build ``probe(heap, bases, starts, lasts, cum, firsts, n_slices,
+    xs) -> (lane, found)``: for each docid in ``xs``, the
+    reverse-chronological lane of its newest posting in a walked chain,
+    found in place, without materialising the chain.
+
+    The slice is the newest one whose first docid (``firsts``, from
+    :func:`chain_first_docids`) is <= x: one compare against every
+    slice.  Inside it, docids ascend with the offset, and a bisection of
+    a fixed number of steps (enough for the largest pool's slice) finds
+    the newest offset whose docid is <= x.  ``found`` is False where x is
+    not in the chain; ``lane`` is then meaningless."""
+    steps = max((size - (p > 0) - 1).bit_length()
+                for p, size in enumerate(layout.slice_sizes))
+
+    def probe(heap, bases, starts, lasts, cum, firsts, n_slices, xs):
+        s = jnp.sum((firsts[None, :] > xs[:, None]).astype(jnp.int32),
+                    axis=1)
+        in_chain = s < n_slices
+        s = jnp.minimum(s, max_slices - 1)
+        base = bases[s]
+        lo, hi = starts[s], lasts[s] + 1      # docid at lo is <= x
+        for _ in range(steps):
+            mid = (lo + hi) // 2
+            le = post.docid(heap[base + mid]) <= xs
+            lo = jnp.where(le, mid, lo)
+            hi = jnp.where(le, hi, mid)
+        found = in_chain & (post.docid(heap[base + lo]) == xs)
+        before = jnp.where(s > 0, cum[jnp.maximum(s - 1, 0)], 0)
+        lane = before + (lasts[s] - lo).astype(jnp.int32)
+        return lane, found
+
+    return probe
 
 
 def make_materializer(layout: PoolLayout, max_slices: int, max_len: int):

@@ -4,6 +4,7 @@ host-loop oracle (``batched=False``) — conjunctive / disjunctive /
 phrase, random streams through >= 2 rollovers, single-device and
 4-shard — and early-exit top-k must equal the full evaluation's
 ``[:k]`` for every k including k > |result|."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -186,31 +187,123 @@ def test_no_frozen_segments_path():
                           _oracle(eng2, "disjunctive", terms))
 
 
-def test_active_topk_fn_matches_engine_topk(engine):
-    """Engine-level: the tiled early-exit active top-k must equal
-    ``QueryEngine.topk_conjunctive`` (full intersection then [:k])."""
+def _topk_case(eng, case):
+    """``(terms, max_len, tile)`` of one active top-k case, its terms
+    picked by their list length in the ACTIVE segment."""
+    freq = np.asarray(eng.segments.active.state.freq)
+    by_len = [int(t) for t in np.argsort(-freq, kind="stable")]
+    absent = int(np.nonzero(freq == 0)[0][0])
+    long_, short = by_len[0], by_len[6]
+    return {
+        "longest_first": ([long_, short], eng.max_len, 128),
+        "shortest_first": ([short, long_], eng.max_len, 128),
+        "absent_term": ([long_, absent, short], eng.max_len, 128),
+        "repeated_term": ([by_len[1], short, by_len[1]], eng.max_len, 128),
+        "four_terms": (by_len[:4], eng.max_len, 128),
+        # both lists outgrow the 64-posting window, so slot 0 drives
+        # (a tie); slot 1 holds more postings a doc, so its window
+        # reaches fewer docs back: its probe must honour the window
+        "window_truncates": ([by_len[1], long_], 64, 16),
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["longest_first", "shortest_first",
+                                  "absent_term", "repeated_term",
+                                  "four_terms", "window_truncates"])
+def test_active_topk_fn_matches_engine_topk(engine, case):
+    """Engine-level: the early-exit active top-k, driven by the row's
+    shortest list and probing the others in place, must equal
+    ``QueryEngine.topk_conjunctive`` (full intersection then [:k]) at
+    the same ``max_len`` window, for every k up to ``k_pad``."""
     from repro.core import query as q
-    eng, freqs = engine
+    eng, _ = engine
     state = eng.segments.active.state
-    engine_q = eng.engine
-    top = np.argsort(-freqs)
-    fn = qexec.make_active_topk_fn(eng.layout, eng.max_slices,
-                                   eng.max_len, eng.max_query_len,
-                                   k_pad=16)
-    for terms in ([int(top[0]), int(top[1])], [int(top[3])],
-                  [int(top[2]), int(top[7]), int(top[11])]):
-        padded = np.zeros((1, eng.max_query_len), np.uint32)
-        padded[0, : len(terms)] = terms
-        for k in (1, 2, 5, 16):
-            got_d, got_n = fn(state, jnp.asarray(padded),
-                              jnp.asarray([len(terms)], np.int32),
-                              jnp.int32(k))
-            exp_d, exp_n = engine_q.topk_conjunctive(
-                state, jnp.asarray(padded[0]), jnp.int32(len(terms)), k)
-            gn, en = int(got_n[0]), int(exp_n)
-            assert gn == en, (terms, k, gn, en)
-            assert np.array_equal(np.asarray(got_d[0])[:gn],
-                                  np.asarray(exp_d)[:en]), (terms, k)
+    terms, max_len, tile = _topk_case(eng, case)
+    k_pad = 32
+    engine_q = q.make_engine(eng.layout, eng.max_slices, max_len,
+                             eng.max_query_len)
+    fn = qexec.make_active_topk_fn(eng.layout, eng.max_slices, max_len,
+                                   k_pad=k_pad, tile=tile)
+    padded = np.zeros((1, eng.max_query_len), np.uint32)
+    padded[0, : len(terms)] = terms
+    row, nt = jnp.asarray(padded[0]), jnp.int32(len(terms))
+    if case == "window_truncates":
+        # the window must bite inside the k range: fewer hits than the
+        # untruncated intersection holds, and fewer than k_pad
+        n_win = int(engine_q.conjunctive(state, row, nt)[1])
+        n_all = int(eng.engine.conjunctive(state, row, nt)[1])
+        assert n_win < min(n_all, k_pad), (n_win, n_all)
+    for k in range(1, k_pad + 1):
+        got_d, got_n, _ = fn(state, jnp.asarray(padded),
+                             jnp.asarray([len(terms)], np.int32),
+                             jnp.int32(k))
+        exp_d, exp_n = engine_q.topk_conjunctive(state, row, nt, k)
+        gn, en = int(got_n[0]), int(exp_n)
+        assert gn == en, (terms, k, gn, en)
+        assert np.array_equal(np.asarray(got_d[0])[:gn],
+                              np.asarray(exp_d)[:en]), (terms, k)
+
+
+def test_active_topk_tiles_counter(engine):
+    """The tile counter: a live row scans at most ceil(shortest window /
+    tile) driver tiles, a row with an empty list scans none, padding rows
+    add nothing, and a ServeLoop's ``ServeStats`` deltas equal the sum of
+    the per-row counts of the batches it dispatched."""
+    from repro.core import serve as sv
+    eng, _ = engine
+    state = eng.segments.active.state
+    freq = np.asarray(state.freq)
+    by_len = [int(t) for t in np.argsort(-freq, kind="stable")]
+    absent = int(np.nonzero(freq == 0)[0][0])
+    queries = [(by_len[0], by_len[1]), (by_len[0], absent),
+               (by_len[2],), (by_len[0], by_len[6], by_len[3])]
+    k, tb = 20, eng.max_query_len
+
+    def run(tile, qs):
+        terms, n_terms = qexec.pad_query_batch(qs, tb)
+        fn = qexec.make_active_topk_fn(eng.layout, eng.max_slices,
+                                       eng.max_len, k_pad=32, tile=tile)
+        _, n, tiles = fn(state, jnp.asarray(terms), jnp.asarray(n_terms),
+                         jnp.int32(k))
+        return np.asarray(n), np.asarray(tiles)
+
+    for tile in (8, 128):
+        n, tiles = run(tile, queries + [(by_len[4],)] * 3)  # 7 rows -> 8
+        assert n.shape == tiles.shape == (8,)
+        for i, qt in enumerate(queries):
+            shortest = min(min(int(freq[t]), eng.max_len) for t in qt)
+            assert 0 <= tiles[i] <= -(-shortest // tile), (tile, qt)
+        assert tiles[1] == 0                        # an empty list
+        assert tiles[7] == 0                        # the padding row
+        assert (tiles[:4] > 0).sum() == 3
+    # 8-lane tiles, k = 20: the first row stops early once 20 hits are
+    # banked; the single-term row 2 reads up to the tile that holds its
+    # 20th distinct docid
+    n, tiles = run(8, queries)
+    assert n[0] == k and tiles[0] < -(-min(freq[by_len[1]],
+                                            eng.max_len) // 8)
+    plist, m = eng.engine.postings_desc(state, jnp.uint32(by_len[2]))
+    ids = np.asarray(plist)[: int(m)] >> 8
+    new_doc = np.r_[True, ids[1:] != ids[:-1]]
+    lane_k = int(np.nonzero(new_doc)[0][k - 1])
+    assert tiles[2] == lane_k // 8 + 1
+
+    loop = sv.ServeLoop(eng, sv.ServeConfig(max_batch=4, batch_wait_s=0.0))
+    loop.force_level = sv.DEGRADE_NONE
+    before = dataclasses.asdict(loop.stats)
+    for qt in queries:
+        loop.submit_query("topk", qt, k=k)
+    loop.drain()
+    after = dataclasses.asdict(loop.stats)
+    terms, n_terms = qexec.pad_query_batch(queries, eng.max_query_len)
+    tb_ = min(qexec.bucket_pow2(int(n_terms.max())), eng.max_query_len)
+    fn = qexec.make_active_topk_fn(eng.layout, eng.max_slices, eng.max_len,
+                                   k_pad=qexec.bucket_pow2(k, 8))
+    _, _, tiles = fn(state, jnp.asarray(terms[:, :tb_]),
+                     jnp.asarray(n_terms), jnp.int32(k))
+    assert (after["topk_tiles_scanned"] - before["topk_tiles_scanned"]
+            == int(np.asarray(tiles).sum()) > 0)
+    assert after["topk_rows_live"] - before["topk_rows_live"] == 4
 
 
 def test_topk_ragged_max_len():

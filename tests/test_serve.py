@@ -150,18 +150,19 @@ def test_flush_on_timer_not_before(warm_engine):
 
 
 def test_mixed_kind_flush_coalesces_per_plan(warm_engine):
-    """One flush with three execution classes -> three dispatches, one
+    """One flush with four execution classes -> four dispatches, one
     response per request, accounting conserved."""
     clock = Clock()
     loop = _loop(warm_engine, clock, max_batch=8)
     loop.force_level = 0
     for q in ((5, 9), (12, 3), (7,)):
         loop.submit_query("conjunctive", q)
-    loop.submit_query("topk", (5, 9), k=4)   # coalesces with conjunctive
+    loop.submit_query("topk", (5, 9), k=4)   # its own class: early exit
+    loop.submit_query("topk", (7,), k=4)     # coalesces with the topk
     loop.submit_query("scored", (5, 9), k=4)
     loop.submit_query("phrase", (5, 9))
-    assert loop.step(force=True) == 6
-    assert loop.stats.batches_dispatched == 3
+    assert loop.step(force=True) == 7
+    assert loop.stats.batches_dispatched == 4
     inv.check_serve(loop).raise_if_failed()
 
 

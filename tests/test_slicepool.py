@@ -1,5 +1,6 @@
 """Allocator invariants: exact agreement with the paper's step function,
 reference-index equivalence, overflow safety, SP start pools."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -65,6 +66,46 @@ def test_materialized_postings_roundtrip(zf):
         assert int(n) == freqs[t]
         exp = posts[terms == t][::-1]
         assert np.array_equal(np.asarray(vals)[: int(n)], exp)
+
+
+@given(z_and_freqs(), st.integers(1, 600))
+@settings(max_examples=15, deadline=None)
+def test_chain_prober_finds_each_docids_newest_lane(zf, gap):
+    """The in-place probe agrees with the materialized chain: every
+    docid of a term's list is found at the lane of its newest posting
+    (a docid's postings may span slices), and no other docid is found.
+    Postings ``i * gap`` put 256 / gap of them in each docid."""
+    z, freqs = zf
+    layout = PoolLayout(z=z, slices_per_pool=tuple(4096 for _ in z))
+    V = len(freqs)
+    terms = np.repeat(np.arange(V, dtype=np.uint32), freqs)
+    posts = np.arange(len(terms), dtype=np.uint32) * np.uint32(gap)
+    state = slicepool.make_ingest_fn(layout, V)(
+        slicepool.init_state(layout, V), jnp.asarray(terms),
+        jnp.asarray(posts))
+    S = max_slices_for(z, freqs)
+    walk = slicepool.make_chain_walker(layout, S)
+    probe = slicepool.make_chain_prober(layout, S)
+
+    @jax.jit
+    def lookup(state, t, xs):
+        bases, starts, lasts, n = walk(state, t)
+        cum = slicepool.chain_lens_cum(starts, lasts, n, S)
+        firsts = slicepool.chain_first_docids(state.heap, bases, starts,
+                                              n, S)
+        return probe(state.heap, bases, starts, lasts, cum, firsts, n, xs)
+
+    for t in range(V):
+        ids = posts[terms == t][::-1] >> 8           # lanes, newest first
+        xs = np.arange(int(ids.min()) - 1 if ids.min() else 0,
+                       int(ids.max()) + 2, dtype=np.uint32)
+        xs = np.pad(xs, (0, 64 - len(xs) % 64), mode="edge")  # few shapes
+        lane, found = lookup(state, jnp.uint32(t), jnp.asarray(xs))
+        want = np.isin(xs, ids)
+        assert np.array_equal(np.asarray(found), want), t
+        newest = {int(d): i for i, d in reversed(list(enumerate(ids)))}
+        assert [int(v) for v in np.asarray(lane)[want]] == \
+            [newest[int(x)] for x in xs[want]], t
 
 
 def test_overflow_sets_flag_and_preserves_data():

@@ -154,6 +154,26 @@ def test_replicated_frozen_merge_compiles_on_four_chips(one_chip, topo):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def test_active_topk_builds_nothing_max_len_wide(one_chip, smoke_layout):
+    """The active early-exit top-k at the Earlybird segment size, four
+    query rows of four term slots: it reads the driver tile by tile and
+    probes the other chains in place, so its temporaries stay below one
+    ``max_len``-wide list (the full conjunction holds rows x slots of
+    them)."""
+    from repro.core import analytical
+    smoke, layout, max_len = smoke_layout
+    sds = _sds(one_chip)
+    max_slices = int(analytical.slices_needed(layout.z, [max_len])[0])
+    state = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: slicepool.init_state(layout, smoke.VOCAB)))
+    Q, T = 4, smoke.MAX_QUERY_LEN
+    fn = qexec.make_active_topk_fn(layout, max_slices, max_len, 32)
+    compiled = fn.lower(state, sds((Q, T), jnp.uint32),
+                        sds((Q,), jnp.int32), sds((), jnp.int32)).compile()
+    assert 0 < compiled.memory_analysis().temp_size_in_bytes < max_len * 4
+
+
 def _compile_ingest(sds, layout, vocab, n):
     state = jax.tree.map(
         lambda x: sds(x.shape, x.dtype),
