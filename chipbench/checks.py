@@ -44,11 +44,22 @@ def check_journal(rec, wal, journaled: bool) -> int:
 
 def check_ingest(cfg, engine, batches, seed: int) -> dict:
     """Per-term counts of the whole vocabulary, and the postings of the
-    head terms plus a seeded sample, read back from the pool."""
+    head terms plus a seeded sample, read back from the pool.
+
+    A sharded state holds ``[S, ...]`` of each: the counts are summed
+    over shards, and each shard's heap is read back on its own, its
+    shard-local docids mapped to global ones (``g = local * S + s``).
+    A term's merged list is right when each shard's list is the
+    reference's postings of the documents dealt to it (``g % S == s``),
+    in order; on one shard that is the whole list."""
     from repro.core.segments import freeze_state
     state = engine.segments.active.state
     V = cfg["vocab"]
-    freq = np.asarray(state.freq).astype(np.int64)
+    freqs = np.asarray(state.freq).astype(np.int64).reshape(-1, V)
+    S = freqs.shape[0]
+    heaps = np.asarray(state.heap).reshape(S, -1)
+    tails = np.asarray(state.tail).reshape(S, -1)
+    freq = freqs.sum(axis=0)
     want = np.zeros(V, np.int64)
     for b in batches:
         want += np.bincount(b.docs[b.docs >= 0], minlength=V)
@@ -64,18 +75,23 @@ def check_ingest(cfg, engine, batches, seed: int) -> dict:
         for t, p in reference.postings_of(b.docs, first, terms).items():
             parts[t].append(p)
         first += b.docs.shape[0]
-    only = np.zeros(V, np.int64)
-    only[terms] = freq[terms]
-    got = freeze_state(engine.layout, np.asarray(state.heap),
-                       np.asarray(state.tail), only,
-                       n_docs=engine.segments.active.next_docid)
-    wrong = sum(1 for t in terms
-                if not np.array_equal(got.postings(int(t)),
-                                      np.concatenate(parts[int(t)])))
+    ref = {t: np.concatenate(p) for t, p in parts.items()}
+    bad = set()
+    for s in range(S):
+        only = np.zeros(V, np.int64)
+        only[terms] = freqs[s, terms]
+        got = freeze_state(engine.layout, heaps[s], tails[s], only,
+                           n_docs=engine.segments.active.next_docid // S)
+        for t in terms:
+            want_s = ref[int(t)]
+            want_s = want_s[(want_s >> reference.POS_BITS) % S == s]
+            if not np.array_equal(
+                    reference.local_to_global(got.postings(int(t)), s, S),
+                    want_s):
+                bad.add(int(t))
     return {"freq_wrong": int(np.sum(freq != want)),
-            "postings_wrong": int(wrong),
-            "postings_read": int(sum(len(np.concatenate(parts[int(t)]))
-                                     for t in terms))}
+            "postings_wrong": len(bad),
+            "postings_read": int(sum(p.size for p in ref.values()))}
 
 
 def check_answers(pool, batches, rec) -> dict:

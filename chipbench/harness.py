@@ -69,9 +69,35 @@ def load_cell(name: str, bench_path: Path = ROOT.parent / "BENCHMARK.json",
 
     def mine(m):
         return m.get("workloads") is None or name in m["workloads"]
-    return Cell(name=name, config=config, mix=mix, chips=int(w["chips"]),
+    cell = Cell(name=name, config=config, mix=mix, chips=int(w["chips"]),
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=[m for m in bench["per_layer"] if mine(m)])
+    check_shards(cell)
+    return cell
+
+
+def shards(cfg: dict) -> int:
+    """The configuration's document shards: the stream is dealt over
+    ``shards`` chips by docid (``g % S``), one shard's index on each."""
+    return int(cfg.get("shards", 1))
+
+
+def check_shards(cell: Cell) -> None:
+    """Refuse a cell its shard count cannot run: every shard holds the
+    same number of a segment's and of a batch's documents, one shard a
+    chip."""
+    cfg, S = cell.config, shards(cell.config)
+    if S < 1:
+        raise ValueError(f"{cell.name}: {S} shards")
+    if S == 1:
+        return
+    for key in ("docs_per_segment", "ingest_batch_docs"):
+        if int(cfg[key]) % S:
+            raise ValueError(f"{cell.name}: {key} {cfg[key]} is not a "
+                             f"multiple of the {S} shards")
+    if cell.chips < S:
+        raise ValueError(f"{cell.name}: {S} shards need {S} chips, the "
+                         f"cell holds {cell.chips}")
 
 
 # ---------------------------------------------------------------------------
@@ -83,31 +109,47 @@ def make_stream(cfg: dict, seed: int) -> streams.TweetStream:
                                seed=seed)
 
 
-def build_engine(cfg: dict):
-    """The configuration's engine.  Pools hold the analytical demand of
-    one segment times the slack, for a Poisson draw around the stream's
-    expected term counts made from the configuration's ``sizing_seed``:
-    the capacity plan is part of the deployment, so every run seed gets
-    the same layout and the same compiled programs."""
+def capacity_plan(cfg: dict):
+    """``(layout, max_len, max_slices)`` of one shard: pools hold the
+    analytical demand of ``docs_per_segment / shards`` documents times
+    the slack, for a Poisson draw around the stream's expected term
+    counts made from the configuration's ``sizing_seed``, and ``max_len``
+    covers that shard's longest list.  The plan is part of the
+    deployment, so every run seed gets the same layout and the same
+    compiled programs."""
     from repro.core import analytical
-    from repro.core.lifecycle import LifecycleEngine
     from repro.core.pointers import PoolLayout
-    from repro.core.segments import CompactionPolicy
 
-    dps = int(cfg["docs_per_segment"])
     plan = make_stream(cfg, cfg["sizing_seed"])
     freqs = np.random.default_rng([cfg["sizing_seed"], 1]).poisson(
-        plan.expected_freqs(dps))
+        plan.expected_freqs(int(cfg["docs_per_segment"]) // shards(cfg)))
     z = tuple(cfg["z"])
     layout = PoolLayout(z=z, slices_per_pool=analytical.slices_per_pool(
         z, freqs, slack=cfg["slack"]))
     max_len = 1 << int(freqs.max() * 1.1).bit_length()
     max_slices = int(analytical.slices_needed(z, [max_len])[0])
-    return LifecycleEngine(
-        layout, cfg["vocab"], dps, max_slices=max_slices, max_len=max_len,
-        max_query_len=cfg["max_query_terms"],
-        stable_shapes=cfg["stable_shapes"],
-        compaction=CompactionPolicy(fanout=cfg["compaction_fanout"]))
+    return layout, max_len, max_slices
+
+
+def build_engine(cfg: dict):
+    """The configuration's engine, on :func:`capacity_plan`'s layout:
+    ``LifecycleEngine`` on one shard, ``ShardedLifecycleEngine`` on a
+    mesh of the first ``shards`` devices otherwise."""
+    from repro.core.lifecycle import LifecycleEngine, ShardedLifecycleEngine
+    from repro.core.segments import CompactionPolicy
+
+    layout, max_len, max_slices = capacity_plan(cfg)
+    dps, S = int(cfg["docs_per_segment"]), shards(cfg)
+    kw = dict(max_slices=max_slices, max_len=max_len,
+              max_query_len=cfg["max_query_terms"],
+              stable_shapes=cfg["stable_shapes"],
+              compaction=CompactionPolicy(fanout=cfg["compaction_fanout"]))
+    if S == 1:
+        return LifecycleEngine(layout, cfg["vocab"], dps, **kw)
+    from repro.core.sharded_index import make_doc_mesh
+    mesh, rules = make_doc_mesh(S)
+    return ShardedLifecycleEngine(layout, cfg["vocab"], dps, mesh,
+                                  rules=rules, **kw)
 
 
 @dataclasses.dataclass
@@ -145,8 +187,12 @@ class FreshnessWatch:
 
     def __init__(self, clock):
         import jax
+        import jax.numpy as jnp
         self.clock = clock
-        self._mark = jax.jit(lambda freq: freq[0] + 1)
+        # one shard's freq is [V]; a sharded state's is [S, V], and its
+        # mark reads every shard, so it waits for each chip's ingest
+        self._mark = {1: jax.jit(lambda freq: freq[0] + 1),
+                      2: jax.jit(lambda freq: jnp.sum(freq[:, 0]))}
         self._q = queue.Queue()
         self.ready = {}          # seq -> clock time
         self._t = threading.Thread(target=self._wait, daemon=True)
@@ -162,7 +208,7 @@ class FreshnessWatch:
             self.ready[seq] = self.clock()
 
     def applied(self, seq: int, state) -> None:
-        self._q.put((seq, self._mark(state.freq)))
+        self._q.put((seq, self._mark[state.freq.ndim](state.freq)))
 
     def close(self) -> None:
         self._q.put(None)
@@ -225,6 +271,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     cfg, mix = cell.config, cell.mix
     if len(devices) < cell.chips:
         raise RuntimeError(f"{cell.name} needs {cell.chips} devices")
+    check_shards(cell)
     comp = CompileLog().__enter__()
     try:
         stream = make_stream(cfg, seed)
@@ -253,8 +300,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         if mix["feed"] == "closed":
             window = feed.make(int(mix["window_docs_max"])
                                // cfg["ingest_batch_docs"])
-            _warm_ingest(loop, window[:1], rec, clock)
-            window = window[1:]
+            window = window[_warm_ingest(loop, window, rec, clock):]
         else:
             n_in = math.ceil(seconds * mix["ingest_docs_per_s"]
                              / cfg["ingest_batch_docs"]) + 1
@@ -266,10 +312,11 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         if fault is not None:
             fault(engine, loop)
         setup_s = clock() - t_start
+        heap = engine.segments.active.state.heap
         log(f"setup: {setup_s:.3f}s, {len(comp.programs)} programs "
-            f"compiled or loaded, heap {engine.segments.active.state.heap.nbytes}"
-            f" bytes, max_len {engine.max_len}, layout "
-            f"{engine.layout.slices_per_pool}")
+            f"compiled or loaded, shards {shards(cfg)}, heap "
+            f"{heap.nbytes // shards(cfg)} bytes a shard, max_len "
+            f"{engine.max_len}, layout {engine.layout.slices_per_pool}")
 
         if sweep:
             return _sweep(cell, loop, engine, window, pool, sweep, seconds,
@@ -339,9 +386,11 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
 def _shapes(cell) -> dict:
     """The kernels' call shapes in the window, for the roofline readers:
-    the (term, posting) entries of one ingest call."""
+    the (term, posting) entries of one ingest batch, and the shards it
+    is dealt over (each chip's call takes ``1 / shards`` of them)."""
     cfg = cell.config
-    return {"ingest_entries": cfg["ingest_batch_docs"] * cfg["doc_width"]}
+    return {"ingest_entries": cfg["ingest_batch_docs"] * cfg["doc_width"],
+            "shards": shards(cfg)}
 
 
 def _attempted(cell, rec) -> int:
@@ -387,12 +436,31 @@ def _setup_ingest(cell, engine, loop, feed, rec, clock) -> None:
                            f"{mix['setup_rollovers']}")
 
 
-def _warm_ingest(loop, batches, rec, clock) -> None:
+# warm-up ingests: one, and one more for a sharded state made on one device
+WARM_INGEST_MAX = 2
+
+
+def _warm_ingest(loop, batches, rec, clock) -> int:
+    """Ingest the first of ``batches``, and a second if the pool state
+    did not come back placed as it went in: the window's ingest program
+    is the one for the settled placement.  (A sharded state starts on
+    one device and is spread over the mesh by its first ingest.)
+    Returns how many batches it took."""
     import jax
-    for b in batches:
-        _submit_ingest(loop, b, rec, clock, window=False)
+
+    def placed():
+        return [x.sharding for x in
+                jax.tree.leaves(loop.engine.segments.active.state)]
+    for n in range(1, WARM_INGEST_MAX + 1):
+        before = placed()
+        _submit_ingest(loop, batches[n - 1], rec, clock, window=False)
         _step(loop)
-    jax.block_until_ready(loop.engine.segments.active.state.heap)
+        if placed() == before:
+            jax.block_until_ready(loop.engine.segments.active.state.heap)
+            return n
+    raise RuntimeError(f"the pool state's placement still changed after "
+                       f"{WARM_INGEST_MAX} warm-up ingests: each would "
+                       f"compile another ingest program")
 
 
 @dataclasses.dataclass

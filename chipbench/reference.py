@@ -39,6 +39,16 @@ def postings_of(docs: np.ndarray, first_docid: int, terms) -> dict:
     return {int(x): packed[a:b] for x, a, b in zip(terms, lo, hi)}
 
 
+def local_to_global(postings: np.ndarray, shard: int,
+                    shards: int) -> np.ndarray:
+    """A document shard's postings with their local docids mapped to
+    global ones (``g = local * shards + shard``), positions kept."""
+    p = np.asarray(postings, np.uint64)
+    ids = (p >> POS_BITS) * shards + shard
+    return ((ids << POS_BITS) | (p & ((1 << POS_BITS) - 1))).astype(
+        np.uint32)
+
+
 class QueryIndex:
     """Per-term ``(docid, tf)`` of a fixed term set, fed batch by batch
     in ingest order (docids count from 0 across every batch)."""

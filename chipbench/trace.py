@@ -40,6 +40,7 @@ class Op:
     name: str
     program: str
     kernel: bool = False          # a Pallas kernel (tpu_custom_call)
+    device: str = ""              # the plane it ran on
 
 
 def op_name(text: str) -> str:
@@ -82,7 +83,9 @@ class Summary:
 
     def program_s(self, pattern: str) -> float:
         """Device time of the programs whose name matches ``pattern``,
-        as the union of their operations, summed over devices."""
+        as the union in time of their operations over every device: the
+        wall time in which some chip ran one (a program on four chips
+        at once counts once)."""
         rx = re.compile(pattern)
         return _busy([o for o in self.ops if rx.fullmatch(o.program)])
 
@@ -93,10 +96,16 @@ class Summary:
 
     def kernel_s(self, program: str) -> float:
         """Device time of the Pallas kernels inside programs named like
-        ``program``."""
+        ``program``: on each device the union of their calls, summed
+        over devices (a kernel on four chips at once counts four times),
+        the time against which a roofline reader sets every chip's
+        bytes."""
         rp = re.compile(program)
-        return _busy([o for o in self.ops
-                      if o.kernel and rp.fullmatch(o.program)])
+        by = defaultdict(list)
+        for o in self.ops:
+            if o.kernel and rp.fullmatch(o.program):
+                by[o.device].append(o)
+        return sum(_busy(ops) for ops in by.values())
 
     def breakdown(self, top: int = TOP) -> dict:
         by = defaultdict(float)
@@ -120,8 +129,8 @@ def reduce(device_ops: Dict[str, List[Op]], host: List[Span],
         raise ValueError("the trace holds no 'window' span")
     lo, hi = wins[0]
     ops, busy, n = [], 0.0, 0
-    for dev in device_ops.values():
-        kept = [Op(s, e, o.name, o.program, o.kernel) for o in dev
+    for name, dev in device_ops.items():
+        kept = [Op(s, e, o.name, o.program, o.kernel, name) for o in dev
                 for s, e in clip([(o.start, o.end)], lo, hi)]
         ops += kept
         busy += _busy(kept)
